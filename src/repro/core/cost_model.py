@@ -118,6 +118,7 @@ class TPUSpec:
     """Hardware constants for the roofline target (TPU v5e-class chip)."""
 
     name: str = "tpu-v5e"
+    device_kind: str = "TPU v5 lite"  # what jax reports for this chip
     peak_flops: float = 197e12  # bf16 FLOP/s per chip
     hbm_bandwidth: float = 819e9  # bytes/s per chip
     ici_bandwidth: float = 50e9  # bytes/s per link

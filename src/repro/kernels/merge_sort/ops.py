@@ -8,11 +8,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.planner import plan_sort
-from repro.kernels.merge_sort.merge_sort import merge_pass, sort_blocks
+from repro.kernels.merge_sort.merge_sort import (
+    LANES, MAX_BLOCK, MIN_BLOCK, merge_pass, sort_blocks,
+)
 from repro.kernels.runtime import resolve_interpret
 
 
-def _next_pow2(n: int) -> int:
+def next_pow2(n: int) -> int:
+    """Smallest power of two >= n (1 for n <= 1)."""
     return 1 << max(0, (n - 1).bit_length())
 
 
@@ -21,32 +24,36 @@ def remop_sort(keys: jnp.ndarray, values: jnp.ndarray | None = None,
                run_items: int | None = None, interpret: bool | None = None):
     """Sort (keys[, values]) ascending via blocked bitonic merge sort.
 
+    Returns ``(sorted_keys, values_in_key_order)``; the second item is
+    ``None`` when no ``values`` were given (the kernels then move keys only).
     `run_items` (power of two) is the in-core run size; defaults to the
-    REMOP sort plan's run for the key dtype.  ``interpret=None`` auto-detects
-    the Pallas mode (compiled on TPU/GPU, interpreter on CPU).
+    REMOP sort plan's run for the key dtype, and is clamped to
+    ``[MIN_BLOCK, MAX_BLOCK]`` keys, one VMEM block.  ``interpret=None``
+    auto-detects the Pallas mode (compiled on TPU/GPU, interpreter on CPU).
+    Keys and values must be 32-bit (the kernels' lane-dense tiles).
     """
     interpret = resolve_interpret(interpret)
     n = keys.shape[0]
-    if values is None:
-        values = jnp.arange(n, dtype=jnp.int32)
     if run_items is None:
         plan = plan_sort(n, item_bytes=keys.dtype.itemsize + 4)
-        run_items = min(_next_pow2(plan.run_items), 1 << 14)
-    run_items = max(2, min(_next_pow2(run_items), _next_pow2(n)))
-    n_pad = max(_next_pow2(n), run_items)
+        run_items = next_pow2(plan.run_items)
+    n_pad = max(next_pow2(n), MIN_BLOCK)
+    block = min(max(next_pow2(run_items), MIN_BLOCK), MAX_BLOCK, n_pad)
     if keys.dtype.kind == "f":
         sentinel = jnp.array(jnp.inf, keys.dtype)
     else:
         sentinel = jnp.array(jnp.iinfo(keys.dtype).max, keys.dtype)
-    kp = jnp.full((n_pad,), sentinel, keys.dtype).at[:n].set(keys)
-    vp = jnp.zeros((n_pad,), values.dtype).at[:n].set(values)
-
-    kp, vp = sort_blocks(kp, vp, min(run_items, n_pad), interpret=interpret)
-    run = min(run_items, n_pad)
+    cols = [jnp.full((n_pad,), sentinel, keys.dtype).at[:n].set(keys)]
+    if values is not None:
+        cols.append(jnp.zeros((n_pad,), values.dtype).at[:n].set(values))
+    cols = sort_blocks(tuple(c.reshape(-1, LANES) for c in cols), block,
+                       interpret=interpret)
+    run = block
     while run < n_pad:
-        kp, vp = merge_pass(kp, vp, run, interpret=interpret)
+        cols = merge_pass(cols, run, block, interpret=interpret)
         run *= 2
-    return kp[:n], vp[:n]
+    out = [c.reshape(-1)[:n] for c in cols]
+    return out[0], (out[1] if values is not None else None)
 
 
 def argsort_by_key(keys: jnp.ndarray, interpret: bool | None = None,

@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 ships the TPU compiler-params dataclass as TPUCompilerParams;
-# newer releases rename it to CompilerParams.  Resolve once, use everywhere.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -88,7 +84,7 @@ def paged_attention(q, k_cache, v_cache, lengths, page: int = 128,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,

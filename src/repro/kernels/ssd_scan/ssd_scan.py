@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 ships the TPU compiler-params dataclass as TPUCompilerParams;
-# newer releases rename it to CompilerParams.  Resolve once, use everywhere.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _ssd_scan_kernel(states_ref, decay_ref, prev_ref, final_ref, carry_ref,
                      *, n_chunks: int):
@@ -69,7 +65,7 @@ def ssd_scan(states: jnp.ndarray, decays: jnp.ndarray,
             jax.ShapeDtypeStruct((b, h, p, n), states.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((h, p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -9,13 +9,17 @@ from __future__ import annotations
 import jax
 
 
+def _auto(n: int):
+    """Auto axes: the sharding rules constrain with ``with_sharding_constraint``,
+    which ``jax.make_mesh``'s default Explicit axes refuse."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2x16x16 = 512 chips across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    # jax 0.4.x has no ``axis_types=`` / ``jax.sharding.AxisType``; Auto is
-    # already the default axis behaviour there.
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, _auto(len(axes)))
 
 
 def make_mesh_for(devices: int, model_parallel: int = None):
@@ -24,4 +28,4 @@ def make_mesh_for(devices: int, model_parallel: int = None):
     while devices % model:
         model //= 2
     data = devices // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"), _auto(2))

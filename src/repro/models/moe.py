@@ -18,15 +18,6 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-# jax >= 0.5 exposes shard_map at the top level with a ``check_vma`` kwarg;
-# 0.4.x has it under jax.experimental with the same check named ``check_rep``.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import constrain, current_sharder
 from repro.models.layers import init_mlp, mlp, truncated_normal
@@ -235,13 +226,13 @@ def _moe_apply_ep(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
             aux = jax.lax.pmean(aux, batch_axes)
         return y, aux
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch_axes if batch_axes else None, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(batch_axes if batch_axes else None, None, None), P()),
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )(x, router_w, w_g, w_u, w_d)
     if batch_axes:
         aux = aux  # identical across batch shards (same formula per shard mean)

@@ -33,6 +33,7 @@ determinism contract — no unseeded RNG — still applies here.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
@@ -43,12 +44,19 @@ import jax.numpy as jnp
 
 from repro.core.cost_model import HierarchySpec, TierSpec
 from repro.kernels.dispatch.dispatch import gather_rows
-from repro.kernels.merge_sort.ops import argsort_by_key, remop_sort
+from repro.kernels.merge_sort.ops import argsort_by_key, next_pow2, remop_sort
 from repro.kernels.runtime import resolve_interpret
 from repro.remote.simulator import MemoryHierarchy, RemoteMemory
 
 _I32_MIN = np.iinfo(np.int32).min
 _I32_MAX = np.iinfo(np.int32).max
+
+
+@functools.partial(jax.jit, static_argnames=("max_key", "interpret"))
+def _group_by_part(rows, parts, max_key: int, interpret: bool):
+    """Rows in stable partition-id order: ``argsort_by_key`` + ``gather_rows``."""
+    order = argsort_by_key(parts, interpret=interpret, max_key=max_key)
+    return gather_rows(rows, order, interpret=interpret)
 
 
 def _device_page(page: np.ndarray) -> Optional[np.ndarray]:
@@ -305,7 +313,10 @@ class ExecutionBackend(MemoryHierarchy):
 
         Byte-identical to ``np.sort(keys, kind="stable")`` — bare keys carry
         no payload, so equal keys are interchangeable.  Blocks that cannot
-        round-trip int32 losslessly fall back to numpy (counted).
+        round-trip int32 losslessly fall back to numpy (counted).  The block
+        is padded with int32-max keys to a power-of-two length before the
+        jit boundary, so a run of many block lengths compiles one program
+        per power of two; the padding sorts last and is dropped.
         """
         keys = np.asarray(keys)
         if keys.ndim != 1 or keys.size < 2:
@@ -314,11 +325,14 @@ class ExecutionBackend(MemoryHierarchy):
         if dev is None:
             self.wall.kernel_fallbacks += 1
             return np.sort(keys, kind="stable")
+        n = len(dev)
+        padded = np.full(next_pow2(n), _I32_MAX, np.int32)
+        padded[:n] = dev
         t0 = time.perf_counter()
-        out, _ = remop_sort(jnp.asarray(dev), interpret=self.interpret)
+        out, _ = remop_sort(jnp.asarray(padded), interpret=self.interpret)
         jax.block_until_ready(out)
         self.wall.record_kernel(time.perf_counter() - t0)
-        return np.asarray(out).astype(keys.dtype, copy=False)
+        return np.asarray(out)[:n].astype(keys.dtype, copy=False)
 
     def partition_rows(
         self, rows: np.ndarray, parts: np.ndarray
@@ -329,6 +343,9 @@ class ExecutionBackend(MemoryHierarchy):
         ``[(q, rows[parts == q]) for q in np.unique(parts)]``, because the
         partition-id argsort is *stable* (within-partition row order is
         preserved) and ``gather_rows`` applies the permutation verbatim.
+        Like :meth:`sort_keys`, the block is padded to a power-of-two length:
+        padded rows get partition ``max_part + 1``, sort after every real row,
+        and are dropped after the gather.
         """
         rows = np.asarray(rows)
         parts = np.asarray(parts)
@@ -336,27 +353,33 @@ class ExecutionBackend(MemoryHierarchy):
             return []
         uniq, counts = np.unique(parts, return_counts=True)
         n = len(parts)
-        max_part = int(uniq[-1])
+        if n < 2:  # one row is already grouped: no kernel, no fallback
+            return [(int(q), rows[parts == q]) for q in uniq]
+        n_pad = next_pow2(n)
+        pad_part = int(uniq[-1]) + 1
+        # The largest id the int32 composite key admits at this length: a
+        # function of n_pad alone, so it adds no program to compile.
+        key_bound = (2**31 - 1) // n_pad - 1
         dev_rows = _device_page(rows) if rows.ndim == 2 else None
         eligible = (
-            n >= 2
-            and dev_rows is not None
+            dev_rows is not None
             and parts.dtype.kind in "iu"
             and int(uniq[0]) >= 0
-            and max_part * n + n < 2**31
+            and pad_part <= key_bound
         )
         if not eligible:
             self.wall.kernel_fallbacks += 1
             return [(int(q), rows[parts == q]) for q in uniq]
+        parts_p = np.full(n_pad, pad_part, np.int32)
+        parts_p[:n] = parts
+        rows_p = np.zeros((n_pad, rows.shape[1]), np.int32)
+        rows_p[:n] = dev_rows
         t0 = time.perf_counter()
-        order = argsort_by_key(jnp.asarray(parts.astype(np.int32)),
-                               interpret=self.interpret, max_key=max_part)
-        gathered = gather_rows(jnp.asarray(dev_rows),
-                               order.astype(jnp.int32),
-                               interpret=self.interpret)
+        gathered = _group_by_part(jnp.asarray(rows_p), jnp.asarray(parts_p),
+                                  max_key=key_bound, interpret=self.interpret)
         jax.block_until_ready(gathered)
         self.wall.record_kernel(time.perf_counter() - t0)
-        ordered = np.asarray(gathered).astype(rows.dtype, copy=False)
+        ordered = np.asarray(gathered)[:n].astype(rows.dtype, copy=False)
         out: List[Tuple[int, np.ndarray]] = []
         start = 0
         for q, c in zip(uniq, counts):

@@ -10,7 +10,7 @@ the same hierarchy spec and demands
 * wall-clock measurements present on the backend and absent on the simulator.
 
 Workloads are deliberately tiny: the Pallas kernels run in interpret mode on
-CPU, where the ``gather_rows`` kernel steps one Python iteration per row.
+CPU, where every distinct block length is a fresh interpreted program.
 """
 
 import dataclasses

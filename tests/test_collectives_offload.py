@@ -33,7 +33,6 @@ def test_bucketed_psum_matches_plain_psum():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed.collectives import bucketed_psum, hierarchical_grad_reduce
 mesh = jax.make_mesh((2, 4), ("pod", "data"))
@@ -42,8 +41,8 @@ tree = {"w": jnp.arange(24.0).reshape(2, 12), "b": jnp.ones((7,))}
 def f(t):
     def local(t):
         return bucketed_psum(t, "data", group_size=4)
-    return shard_map(local, mesh, in_specs=P(), out_specs=P(),
-                     check_rep=False)(t)
+    return jax.shard_map(local, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(t)
 
 got = jax.jit(f)(tree)
 want = jax.tree.map(lambda x: x * 4.0, tree)  # psum over data axis (size 4)
@@ -53,8 +52,8 @@ for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
 def h(t):
     def local(t):
         return hierarchical_grad_reduce(t, "data", "pod")
-    return shard_map(local, mesh, in_specs=P(), out_specs=P(),
-                     check_rep=False)(t)
+    return jax.shard_map(local, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(t)
 
 got2 = jax.jit(h)(tree)
 want2 = jax.tree.map(lambda x: x * 8.0, tree)  # full 2x4 reduction

@@ -22,6 +22,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
     """Train step on a (2,4) mesh must produce the same loss as 1 device."""
     print(_run("""
 import jax, jax.numpy as jnp, numpy as np
+AUTO = (jax.sharding.AxisType.Auto,) * 2
 from repro.configs import ARCHS, reduced
 from repro.distributed.sharding import Sharder
 from repro.launch import steps as steps_lib
@@ -34,7 +35,7 @@ shape = ShapeSpec("t", seq_len=32, global_batch=8, kind="train")
 batch = jax.tree.map(jnp.asarray, next(synthetic_batches(cfg, shape, seed=0)))
 opt = AdamWConfig(lr=1e-3, total_steps=10, warmup_steps=1)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), AUTO)
 sharder = Sharder(mesh, sequence_parallel=True)
 state = steps_lib.init_state(cfg, jax.random.key(0))
 st_shard = steps_lib.state_shardings(state["params"], mesh, sharder)
@@ -60,6 +61,7 @@ def test_sharded_decode_matches_prefill_consistency():
     """Sharded decode step reproduces unsharded logits."""
     print(_run("""
 import jax, jax.numpy as jnp, numpy as np
+AUTO = (jax.sharding.AxisType.Auto,) * 2
 from repro.configs import ARCHS, reduced
 from repro.distributed.sharding import Sharder
 from repro.launch import specs as specs_lib, steps as steps_lib
@@ -74,7 +76,7 @@ caches = tf.pad_caches(cfg, caches, 16)
 want, _ = tf.decode_step(params, cfg, caches, tokens[:, 11],
                          jnp.asarray(11, jnp.int32))
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), AUTO)
 sharder = Sharder(mesh, sequence_parallel=False)
 p_shard = shlib.named_sharding_tree(shlib.param_specs(params, sharder), mesh)
 c_shard = specs_lib.cache_shardings(cfg, sharder, caches)
@@ -96,6 +98,7 @@ def test_elastic_reshard_roundtrip(tmp_path):
     """Checkpoint on a (2,4) mesh, restore onto (4,2) — elastic re-scale."""
     print(_run(f"""
 import jax, jax.numpy as jnp, numpy as np
+AUTO = (jax.sharding.AxisType.Auto,) * 2
 from repro.configs import ARCHS, reduced
 from repro.distributed.sharding import Sharder
 from repro.launch import steps as steps_lib
@@ -107,13 +110,13 @@ cfg = reduced(ARCHS["qwen3-0.6b"], n_kv_heads=4)
 params = tf.init_params(jax.random.key(0), cfg)
 store = CheckpointStore({str(tmp_path)!r})
 
-mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+mesh1 = jax.make_mesh((2, 4), ("data", "model"), AUTO)
 s1 = Sharder(mesh1)
 shard1 = shlib.named_sharding_tree(shlib.param_specs(params, s1), mesh1)
 p1 = jax.tree.map(lambda x, s: jax.device_put(x, s), params, shard1)
 store.save(7, p1, {{"step": 7}}, blocking=True)
 
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = jax.make_mesh((4, 2), ("data", "model"), AUTO)
 s2 = Sharder(mesh2)
 shard2 = shlib.named_sharding_tree(shlib.param_specs(params, s2), mesh2)
 step, restored, meta = store.restore_latest(params, shard2)
@@ -128,6 +131,7 @@ def test_moe_ep_shard_map_matches_gspmd():
     """The shard_map EP dispatch (§Perf winner) is numerically exact."""
     print(_run("""
 import jax, jax.numpy as jnp
+AUTO = (jax.sharding.AxisType.Auto,) * 2
 from repro.configs import ARCHS, reduced
 from repro.distributed.sharding import Sharder, use_sharder
 from repro.models import moe as moe_mod, transformer as tf
@@ -136,7 +140,7 @@ cfg = reduced(ARCHS["deepseek-v2-lite-16b"], n_experts=8, experts_per_token=2,
 params = tf.init_params(jax.random.key(0), cfg)
 tokens = jax.random.randint(jax.random.key(1), (4, 16), 0, cfg.vocab_size)
 batch = {"tokens": tokens, "targets": tokens}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"), AUTO)
 sharder = Sharder(mesh, sequence_parallel=False)
 def loss(p):
     with use_sharder(sharder):
