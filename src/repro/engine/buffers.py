@@ -27,6 +27,7 @@ from typing import Dict, Hashable, Iterator, List, Optional, Sequence
 import numpy as np
 
 from repro.engine.scheduler import TransferScheduler
+from repro.spans import span
 
 
 class BufferPool:
@@ -61,22 +62,24 @@ class BufferPool:
         """Buffer rows on a stream; flush full slices as batched write rounds."""
         if not len(rows):
             return
-        self._bufs.setdefault(stream, []).append(rows)
-        self._counts[stream] = self._counts.get(stream, 0) + len(rows)
-        if self._counts[stream] >= self.slice_rows:
-            self._drain(stream, force=False)
+        with span("pool.add"):
+            self._bufs.setdefault(stream, []).append(rows)
+            self._counts[stream] = self._counts.get(stream, 0) + len(rows)
+            if self._counts[stream] >= self.slice_rows:
+                self._drain(stream, force=False)
 
     def _drain(self, stream: Hashable, force: bool) -> None:
-        bufs = self._bufs.get(stream, [])
-        data = bufs[0] if len(bufs) == 1 else np.concatenate(bufs, axis=0)
-        while len(data) >= self.slice_rows:
-            self._write_round(stream, data[: self.slice_rows])
-            data = data[self.slice_rows :]
-        if force and len(data):
-            self._write_round(stream, data)
-            data = data[:0]
-        self._bufs[stream] = [data] if len(data) else []
-        self._counts[stream] = len(data)
+        with span("pool.flush"):
+            bufs = self._bufs.get(stream, [])
+            data = bufs[0] if len(bufs) == 1 else np.concatenate(bufs, axis=0)
+            while len(data) >= self.slice_rows:
+                self._write_round(stream, data[: self.slice_rows])
+                data = data[self.slice_rows :]
+            if force and len(data):
+                self._write_round(stream, data)
+                data = data[:0]
+            self._bufs[stream] = [data] if len(data) else []
+            self._counts[stream] = len(data)
 
     def _write_round(self, stream: Hashable, chunk: np.ndarray) -> None:
         pages = [
@@ -203,9 +206,10 @@ class PageCursor:
         return np.concatenate(list(self.blocks()), axis=0)
 
     def _concat(self, pages: List[np.ndarray]) -> np.ndarray:
-        if self.ravel:
-            return np.concatenate([p.ravel() for p in pages])
-        return pages[0] if len(pages) == 1 else np.concatenate(pages, axis=0)
+        with span("cursor.block"):
+            if self.ravel:
+                return np.concatenate([p.ravel() for p in pages])
+            return pages[0] if len(pages) == 1 else np.concatenate(pages, axis=0)
 
     def _read_next(self) -> List[np.ndarray]:
         ids = self.page_ids[self.pos : self.pos + self.batch_pages]

@@ -53,6 +53,7 @@ from repro.engine.registry import (
     resolve_tier,
 )
 from repro.engine.scheduler import TransferScheduler, stream_tiers
+from repro.spans import span
 
 # --------------------------------------------------------------------------
 # Typed tasks
@@ -907,7 +908,8 @@ class Session:
             saved_policy = self.evictor.policy
             self.evictor.policy = task.eviction
         try:
-            result = spec.run(self.remote, *args, ob.plan, **kwargs)
+            with span(f"task.{task.op}"):
+                result = spec.run(self.remote, *args, ob.plan, **kwargs)
             delta = sched.since(label)
         finally:
             sched.drop_checkpoint(label)

@@ -77,5 +77,6 @@ def gather_rows(x: jnp.ndarray, idx: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((n_pad, lanes), x.dtype),
         scratch_shapes=[pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
+        name="gather_rows",
     )(ip, xp)
     return out[:n, :d]

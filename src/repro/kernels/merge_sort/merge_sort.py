@@ -102,6 +102,7 @@ def _blocked_call(cols: Cols, block: int, stages, interpret: bool) -> Cols:
         out_specs=[spec] * len(cols),
         out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in cols],
         interpret=interpret,
+        name="bitonic_block",
     )(*cols)
     return tuple(out)
 

@@ -47,6 +47,7 @@ from repro.kernels.dispatch.dispatch import gather_rows
 from repro.kernels.merge_sort.ops import argsort_by_key, next_pow2, remop_sort
 from repro.kernels.runtime import resolve_interpret
 from repro.remote.simulator import MemoryHierarchy, RemoteMemory
+from repro.spans import count, span
 
 _I32_MIN = np.iinfo(np.int32).min
 _I32_MAX = np.iinfo(np.int32).max
@@ -54,9 +55,15 @@ _I32_MAX = np.iinfo(np.int32).max
 
 @functools.partial(jax.jit, static_argnames=("max_key", "interpret"))
 def _group_by_part(rows, parts, max_key: int, interpret: bool):
-    """Rows in stable partition-id order: ``argsort_by_key`` + ``gather_rows``."""
-    order = argsort_by_key(parts, interpret=interpret, max_key=max_key)
-    return gather_rows(rows, order, interpret=interpret)
+    """Rows in stable partition-id order: ``argsort_by_key`` + ``gather_rows``.
+
+    The two scopes name the halves apart in the device trace; the module
+    stays ``jit__group_by_part``.
+    """
+    with jax.named_scope("argsort"):
+        order = argsort_by_key(parts, interpret=interpret, max_key=max_key)
+    with jax.named_scope("gather_rows"):
+        return gather_rows(rows, order, interpret=interpret)
 
 
 def _device_page(page: np.ndarray) -> Optional[np.ndarray]:
@@ -193,18 +200,20 @@ class BackendTier(RemoteMemory):
 
     def _mirror(self, page_ids: Sequence[int]) -> None:
         views = []
-        for i in page_ids:
-            v = _device_page(self._store[i])
-            if v is None:
-                self._wall.host_pinned_pages += 1
-            else:
-                views.append((i, v))
+        with span("tier.check"):
+            for i in page_ids:
+                v = _device_page(self._store[i])
+                if v is None:
+                    self._wall.host_pinned_pages += 1
+                else:
+                    views.append((i, v))
         if not views:
             return
         nbytes = sum(v.nbytes for _, v in views)
         t0 = time.perf_counter()
-        arrays = jax.device_put([v for _, v in views], self._device)
-        jax.block_until_ready(arrays)
+        with span("tier.put"):
+            arrays = jax.device_put([v for _, v in views], self._device)
+            jax.block_until_ready(arrays)
         elapsed = time.perf_counter() - t0
         if self._in_write:  # seeding (put_local) is not a transfer round
             self._wall.record_h2d(self.tier.name, elapsed, nbytes)
@@ -221,32 +230,36 @@ class BackendTier(RemoteMemory):
     def write_batch(self, pages: Sequence[np.ndarray]) -> List[int]:
         if not len(pages):
             return []
-        self._in_write = True
-        try:
-            return super().write_batch(pages)  # ledger + put_local -> mirror
-        finally:
-            self._in_write = False
+        with span("tier.write"):
+            self._in_write = True
+            try:
+                return super().write_batch(pages)  # ledger + put_local -> mirror
+            finally:
+                self._in_write = False
 
     def read_batch(self, page_ids: Sequence[int], prefetched: bool = False) -> List[np.ndarray]:
         if not page_ids:
             return []
-        host = super().read_batch(page_ids, prefetched)  # identical ledger
-        mirrors = [self._dev.get(i) for i in page_ids]
-        fetched: List[Optional[np.ndarray]] = [None] * len(page_ids)
-        live = [(k, d) for k, d in enumerate(mirrors) if d is not None]
-        if live:
-            t0 = time.perf_counter()
-            pulled = [np.asarray(d) for _, d in live]
-            elapsed = time.perf_counter() - t0
-            self._wall.record_d2h(
-                self.tier.name, elapsed, sum(p.nbytes for p in pulled)
-            )
-            for (k, _), p in zip(live, pulled):
-                fetched[k] = p
-        return [
-            h if f is None else f.astype(h.dtype, copy=False)
-            for h, f in zip(host, fetched)
-        ]
+        with span("tier.read"):
+            host = super().read_batch(page_ids, prefetched)  # identical ledger
+            mirrors = [self._dev.get(i) for i in page_ids]
+            fetched: List[Optional[np.ndarray]] = [None] * len(page_ids)
+            live = [(k, d) for k, d in enumerate(mirrors) if d is not None]
+            if live:
+                t0 = time.perf_counter()
+                with span("tier.pull"):
+                    pulled = [np.asarray(d) for _, d in live]
+                elapsed = time.perf_counter() - t0
+                self._wall.record_d2h(
+                    self.tier.name, elapsed, sum(p.nbytes for p in pulled)
+                )
+                for (k, _), p in zip(live, pulled):
+                    fetched[k] = p
+            with span("tier.cast"):
+                return [
+                    h if f is None else f.astype(h.dtype, copy=False)
+                    for h, f in zip(host, fetched)
+                ]
 
     def free(self, page_ids: Iterable[int]) -> None:
         ids = list(page_ids)
@@ -318,21 +331,29 @@ class ExecutionBackend(MemoryHierarchy):
         jit boundary, so a run of many block lengths compiles one program
         per power of two; the padding sorts last and is dropped.
         """
-        keys = np.asarray(keys)
-        if keys.ndim != 1 or keys.size < 2:
-            return np.sort(keys, kind="stable")
-        dev = _device_page(keys) if keys.dtype.kind in "iu" else None
-        if dev is None:
-            self.wall.kernel_fallbacks += 1
-            return np.sort(keys, kind="stable")
-        n = len(dev)
-        padded = np.full(next_pow2(n), _I32_MAX, np.int32)
-        padded[:n] = dev
-        t0 = time.perf_counter()
-        out, _ = remop_sort(jnp.asarray(padded), interpret=self.interpret)
-        jax.block_until_ready(out)
-        self.wall.record_kernel(time.perf_counter() - t0)
-        return np.asarray(out)[:n].astype(keys.dtype, copy=False)
+        with span("hook.sort_keys"):
+            with span("hook.prepare"):
+                keys = np.asarray(keys)
+                if keys.ndim != 1 or keys.size < 2:
+                    return np.sort(keys, kind="stable")
+                dev = _device_page(keys) if keys.dtype.kind in "iu" else None
+                if dev is None:
+                    self.wall.kernel_fallbacks += 1
+                    return np.sort(keys, kind="stable")
+                n = len(dev)
+                padded = np.full(next_pow2(n), _I32_MAX, np.int32)
+                padded[:n] = dev
+            count("sort.keys", n)
+            count("sort.padded_keys", len(padded))
+            t0 = time.perf_counter()
+            with span("hook.upload"):
+                padded = jnp.asarray(padded)
+            with span("hook.device"):
+                out, _ = remop_sort(padded, interpret=self.interpret)
+                jax.block_until_ready(out)
+            self.wall.record_kernel(time.perf_counter() - t0)
+            with span("hook.download"):
+                return np.asarray(out)[:n].astype(keys.dtype, copy=False)
 
     def partition_rows(
         self, rows: np.ndarray, parts: np.ndarray
@@ -347,45 +368,56 @@ class ExecutionBackend(MemoryHierarchy):
         padded rows get partition ``max_part + 1``, sort after every real row,
         and are dropped after the gather.
         """
-        rows = np.asarray(rows)
-        parts = np.asarray(parts)
-        if not len(rows):
-            return []
-        uniq, counts = np.unique(parts, return_counts=True)
-        n = len(parts)
-        if n < 2:  # one row is already grouped: no kernel, no fallback
-            return [(int(q), rows[parts == q]) for q in uniq]
-        n_pad = next_pow2(n)
-        pad_part = int(uniq[-1]) + 1
-        # The largest id the int32 composite key admits at this length: a
-        # function of n_pad alone, so it adds no program to compile.
-        key_bound = (2**31 - 1) // n_pad - 1
-        dev_rows = _device_page(rows) if rows.ndim == 2 else None
-        eligible = (
-            dev_rows is not None
-            and parts.dtype.kind in "iu"
-            and int(uniq[0]) >= 0
-            and pad_part <= key_bound
-        )
-        if not eligible:
-            self.wall.kernel_fallbacks += 1
-            return [(int(q), rows[parts == q]) for q in uniq]
-        parts_p = np.full(n_pad, pad_part, np.int32)
-        parts_p[:n] = parts
-        rows_p = np.zeros((n_pad, rows.shape[1]), np.int32)
-        rows_p[:n] = dev_rows
-        t0 = time.perf_counter()
-        gathered = _group_by_part(jnp.asarray(rows_p), jnp.asarray(parts_p),
-                                  max_key=key_bound, interpret=self.interpret)
-        jax.block_until_ready(gathered)
-        self.wall.record_kernel(time.perf_counter() - t0)
-        ordered = np.asarray(gathered)[:n].astype(rows.dtype, copy=False)
-        out: List[Tuple[int, np.ndarray]] = []
-        start = 0
-        for q, c in zip(uniq, counts):
-            out.append((int(q), ordered[start:start + int(c)]))
-            start += int(c)
-        return out
+        with span("hook.partition_rows"):
+            with span("hook.prepare"):
+                rows = np.asarray(rows)
+                parts = np.asarray(parts)
+                if not len(rows):
+                    return []
+                uniq, counts = np.unique(parts, return_counts=True)
+                n = len(parts)
+                if n < 2:  # one row is already grouped: no kernel, no fallback
+                    return [(int(q), rows[parts == q]) for q in uniq]
+                n_pad = next_pow2(n)
+                pad_part = int(uniq[-1]) + 1
+                # The largest id the int32 composite key admits at this
+                # length: a function of n_pad alone, so it adds no program.
+                key_bound = (2**31 - 1) // n_pad - 1
+                dev_rows = _device_page(rows) if rows.ndim == 2 else None
+                eligible = (
+                    dev_rows is not None
+                    and parts.dtype.kind in "iu"
+                    and int(uniq[0]) >= 0
+                    and pad_part <= key_bound
+                )
+                if eligible:
+                    parts_p = np.full(n_pad, pad_part, np.int32)
+                    parts_p[:n] = parts
+                    rows_p = np.zeros((n_pad, rows.shape[1]), np.int32)
+                    rows_p[:n] = dev_rows
+            if not eligible:
+                self.wall.kernel_fallbacks += 1
+                with span("hook.split"):
+                    return [(int(q), rows[parts == q]) for q in uniq]
+            count("partition.rows", n)
+            count("partition.padded_rows", n_pad)
+            t0 = time.perf_counter()
+            with span("hook.upload"):
+                rows_p, parts_p = jnp.asarray(rows_p), jnp.asarray(parts_p)
+            with span("hook.device"):
+                gathered = _group_by_part(rows_p, parts_p, max_key=key_bound,
+                                          interpret=self.interpret)
+                jax.block_until_ready(gathered)
+            self.wall.record_kernel(time.perf_counter() - t0)
+            with span("hook.download"):
+                ordered = np.asarray(gathered)[:n].astype(rows.dtype, copy=False)
+            with span("hook.split"):
+                out: List[Tuple[int, np.ndarray]] = []
+                start = 0
+                for q, c in zip(uniq, counts):
+                    out.append((int(q), ordered[start:start + int(c)]))
+                    start += int(c)
+                return out
 
 
 def make_backend(

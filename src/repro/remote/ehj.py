@@ -19,6 +19,7 @@ from typing import Dict, List
 import numpy as np
 
 from repro.core.policies import EHJPlan
+from repro.spans import count, span
 from repro.engine.buffers import BufferPool, PageCursor
 from repro.engine.scheduler import TransferScheduler, stream_tiers
 from repro.remote.bnlj import _block_join
@@ -93,73 +94,86 @@ def ehj(
     phase_rounds: Dict[str, int] = {}
 
     def hash_part(keys: np.ndarray) -> np.ndarray:
-        h = keys.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
-        return ((h >> np.uint64(33)) % np.uint64(p)).astype(np.int64)
+        with span("ehj.hash"):
+            h = keys.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
+            return ((h >> np.uint64(33)) % np.uint64(p)).astype(np.int64)
+
+    def join(build_rows: np.ndarray, probe_rows: np.ndarray) -> np.ndarray:
+        with span("ehj.join"):
+            matched = _block_join(build_rows, probe_rows)
+        count("ehj.join_calls")
+        count("ehj.join_rows_out", len(matched))
+        return matched
 
     # ---- P1: partition build, build resident tables, spill the rest -------
-    t0 = sched.snapshot()
-    r_r1, r_w1 = plan.p1
-    build_pool = BufferPool(sched, r_w1, rows_per_page,
-                            n_streams=max(len(spilled), 1),
-                            tier=tiers["build"])
-    resident_build: Dict[int, List[np.ndarray]] = {q: [] for q in range(p) if q not in spilled}
-    for rows in PageCursor(sched, build.page_ids, round(r_r1),
-                           prefetch=prefetch).blocks():
-        parts = hash_part(rows[:, 0])
-        for q, sel in sched.partitions(rows, parts):
-            if q in spilled:
-                build_pool.add(sel, stream=q)
-            else:
-                resident_build[q].append(sel)
-    build_pool.flush_all()
-    resident_tables = {
-        q: (np.concatenate(v, axis=0) if v else np.empty((0, 2), dtype=np.int64))
-        for q, v in resident_build.items()
-    }
-    phase_rounds["P1"] = sched.delta(t0).c_total
+    with span("ehj.P1"):
+        t0 = sched.snapshot()
+        r_r1, r_w1 = plan.p1
+        build_pool = BufferPool(sched, r_w1, rows_per_page,
+                                n_streams=max(len(spilled), 1),
+                                tier=tiers["build"])
+        resident_build: Dict[int, List[np.ndarray]] = {
+            q: [] for q in range(p) if q not in spilled}
+        for rows in PageCursor(sched, build.page_ids, round(r_r1),
+                               prefetch=prefetch).blocks():
+            parts = hash_part(rows[:, 0])
+            for q, sel in sched.partitions(rows, parts):
+                if q in spilled:
+                    build_pool.add(sel, stream=q)
+                else:
+                    resident_build[q].append(sel)
+        build_pool.flush_all()
+        with span("ehj.table"):
+            resident_tables = {
+                q: (np.concatenate(v, axis=0) if v else np.empty((0, 2), dtype=np.int64))
+                for q, v in resident_build.items()
+            }
+        phase_rounds["P1"] = sched.delta(t0).c_total
 
     # ---- P2: partition probe; probe resident, stage spilled ----------------
-    t0 = sched.snapshot()
-    r_r2, r_s2, r_o2 = plan.p2
-    stage_pool = BufferPool(sched, r_s2, rows_per_page,
-                            n_streams=max(len(spilled), 1),
-                            tier=tiers["stage"])
-    out_pool = BufferPool(sched, r_o2, rows_per_page, tier=tiers["output"])
-    output_rows = 0
-    for rows in PageCursor(sched, probe.page_ids, round(r_r2),
-                           prefetch=prefetch).blocks():
-        parts = hash_part(rows[:, 0])
-        for q, sel in sched.partitions(rows, parts):
-            if q in spilled:
-                stage_pool.add(sel, stream=q)
-            else:
-                matched = _block_join(resident_tables[q], sel)
-                if len(matched):
-                    output_rows += len(matched)
-                    out_pool.add(matched)  # single resident-output stream
-    stage_pool.flush_all()
-    phase_rounds["P2"] = sched.delta(t0).c_total
+    with span("ehj.P2"):
+        t0 = sched.snapshot()
+        r_r2, r_s2, r_o2 = plan.p2
+        stage_pool = BufferPool(sched, r_s2, rows_per_page,
+                                n_streams=max(len(spilled), 1),
+                                tier=tiers["stage"])
+        out_pool = BufferPool(sched, r_o2, rows_per_page, tier=tiers["output"])
+        output_rows = 0
+        for rows in PageCursor(sched, probe.page_ids, round(r_r2),
+                               prefetch=prefetch).blocks():
+            parts = hash_part(rows[:, 0])
+            for q, sel in sched.partitions(rows, parts):
+                if q in spilled:
+                    stage_pool.add(sel, stream=q)
+                else:
+                    matched = join(resident_tables[q], sel)
+                    if len(matched):
+                        output_rows += len(matched)
+                        out_pool.add(matched)  # single resident-output stream
+        stage_pool.flush_all()
+        phase_rounds["P2"] = sched.delta(t0).c_total
 
     # ---- P3: external rounds over spilled pairs ----------------------------
-    t0 = sched.snapshot()
-    r_r3, r_o3 = plan.p3
-    read_pages = round(r_r3)
-    ext_out_pool = BufferPool(sched, r_o3, rows_per_page, tier=tiers["output"])
-    for q in sorted(spilled):
-        b_ids = build_pool.pages(q)
-        q_ids = stage_pool.pages(q)
-        if not b_ids or not q_ids:
-            continue
-        b_rows = PageCursor(sched, b_ids, read_pages, prefetch=prefetch).read_all()
-        for q_rows in PageCursor(sched, q_ids, read_pages,
-                                 prefetch=prefetch).blocks():
-            matched = _block_join(b_rows, q_rows)
-            if len(matched):
-                output_rows += len(matched)
-                ext_out_pool.add(matched, stream=q)
-    out_pool.flush_all()
-    ext_out_pool.flush_all()
-    phase_rounds["P3"] = sched.delta(t0).c_total
+    with span("ehj.P3"):
+        t0 = sched.snapshot()
+        r_r3, r_o3 = plan.p3
+        read_pages = round(r_r3)
+        ext_out_pool = BufferPool(sched, r_o3, rows_per_page, tier=tiers["output"])
+        for q in sorted(spilled):
+            b_ids = build_pool.pages(q)
+            q_ids = stage_pool.pages(q)
+            if not b_ids or not q_ids:
+                continue
+            b_rows = PageCursor(sched, b_ids, read_pages, prefetch=prefetch).read_all()
+            for q_rows in PageCursor(sched, q_ids, read_pages,
+                                     prefetch=prefetch).blocks():
+                matched = join(b_rows, q_rows)
+                if len(matched):
+                    output_rows += len(matched)
+                    ext_out_pool.add(matched, stream=q)
+        out_pool.flush_all()
+        ext_out_pool.flush_all()
+        phase_rounds["P3"] = sched.delta(t0).c_total
 
     d = sched.delta(before)
     output_ids = list(out_pool.pages())

@@ -20,6 +20,7 @@ from repro.core.policies import EMSPlan
 from repro.engine.buffers import BufferPool, PageCursor
 from repro.engine.scheduler import TransferScheduler, stream_tiers
 from repro.remote.simulator import RemoteMemory
+from repro.spans import span
 
 
 # Typed input signature for the session API: ``engine.registry`` binds named
@@ -118,39 +119,41 @@ def ems_sort(
 
     # ---- run formation: sort M-page chunks locally (§III-B a) -------------
     runs: List[List[int]] = []
-    for start in range(0, len(page_ids), m_pages):
-        ids = page_ids[start : start + m_pages]
-        if count_run_formation:
-            pages = sched.read(ids)  # 1 round
-        else:
-            pages = remote.peek_batch(ids)
-        data = sched.sort_keys(np.concatenate([p.ravel() for p in pages]))
-        out_pages = [data[i : i + rows_per_page] for i in range(0, len(data), rows_per_page)]
-        if count_run_formation:
-            runs.append(sched.write(out_pages, tier=tiers["runs"]))  # 1 round
-        else:
-            runs.append(remote.put_local(out_pages))
+    with span("ems.runs"):
+        for start in range(0, len(page_ids), m_pages):
+            ids = page_ids[start : start + m_pages]
+            if count_run_formation:
+                pages = sched.read(ids)  # 1 round
+            else:
+                pages = remote.peek_batch(ids)
+            data = sched.sort_keys(np.concatenate([p.ravel() for p in pages]))
+            out_pages = [data[i : i + rows_per_page] for i in range(0, len(data), rows_per_page)]
+            if count_run_formation:
+                runs.append(sched.write(out_pages, tier=tiers["runs"]))  # 1 round
+            else:
+                runs.append(remote.put_local(out_pages))
 
     # ---- merge passes (Algorithm 2) ----------------------------------------
     passes = 0
-    while len(runs) > 1:
-        # The last pass (a single merge group) writes the *output* stream;
-        # every earlier pass writes intermediate runs.
-        final = len(runs) <= plan.k
-        out_tier = tiers["output"] if final else tiers["runs"]
-        nxt: List[List[int]] = []
-        for g in range(0, len(runs), plan.k):
-            group = runs[g : g + plan.k]
-            if len(group) == 1:
-                nxt.append(group[0])
-            else:
-                nxt.append(
-                    _merge_group(
-                        sched, group, plan, rows_per_page, prefetch, out_tier=out_tier
+    with span("ems.merge"):
+        while len(runs) > 1:
+            # The last pass (a single merge group) writes the *output* stream;
+            # every earlier pass writes intermediate runs.
+            final = len(runs) <= plan.k
+            out_tier = tiers["output"] if final else tiers["runs"]
+            nxt: List[List[int]] = []
+            for g in range(0, len(runs), plan.k):
+                group = runs[g : g + plan.k]
+                if len(group) == 1:
+                    nxt.append(group[0])
+                else:
+                    nxt.append(
+                        _merge_group(
+                            sched, group, plan, rows_per_page, prefetch, out_tier=out_tier
+                        )
                     )
-                )
-        runs = nxt
-        passes += 1
+            runs = nxt
+            passes += 1
 
     d = sched.delta(before)
     return SortResult(

@@ -70,3 +70,18 @@ def test_partition_compiles_for_v5e(one_chip, width):
     compiled = _group_by_part.lower(rows, parts, max_key=8,
                                     interpret=False).compile()
     _assert_kernel_program(compiled, 5)  # sort: 1 + 3 merges; 1 gather
+
+
+def test_partition_program_names_its_kernels(one_chip):
+    """The device trace tells the gather from the sort by these names, and
+    ``chipbench/kernels/partition.py`` finds the module by its own."""
+    n = 1 << 14
+    rows = jax.ShapeDtypeStruct((n, 2), jnp.int32, sharding=one_chip)
+    parts = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    text = _group_by_part.lower(rows, parts, max_key=8, interpret=False).compile().as_text()
+    assert text.startswith("HloModule jit__group_by_part,")
+    kernels = [line.split(" = ", 1)[0].split()[-1] for line in text.splitlines()
+               if "tpu_custom_call" in line and " = " in line]
+    assert {k.rsplit(".", 1)[0] for k in kernels} == {"%gather_rows", "%bitonic_block"}
+    assert 'op_name="jit(_group_by_part)/argsort/' in text
+    assert 'op_name="jit(_group_by_part)/gather_rows/' in text
