@@ -2,10 +2,14 @@
 
 Algorithm 3 / §III-C: both relations are hash-partitioned into P partitions; a
 fraction ``sigma`` of partitions spill.  Phase P1 partitions the build side
-(resident partitions become in-memory hash tables, spilled tuples flush
-through the R_w write pool); P2 partitions the probe side (resident tuples
-probe on the fly, spilled tuples stage through R_s, resident output through
-R_o); P3 re-reads each spilled pair and joins it.  The R_w/R_s/R_o pools are
+(resident partitions become in-memory sorted key indexes, spilled tuples
+flush through the R_w write pool); P2 partitions the probe side (resident
+tuples probe on the fly, spilled tuples stage through R_s, resident output
+through R_o); P3 re-reads each spilled pair, indexes its build partition once
+and probes it block by block.  A partition's table is a :class:`KeyIndex`:
+its keys are sorted once, and each probe row is searched in them once
+(``probe_index``), so a partition probed in many blocks is never searched
+again per block.  The R_w/R_s/R_o pools are
 per-partition-sliced :class:`repro.engine.BufferPool` instances and every
 block read is a :class:`repro.engine.PageCursor` round, so the ledger counts
 match the Table V terms.
@@ -14,7 +18,7 @@ match the Table V terms.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import Dict, List, NamedTuple
 
 import numpy as np
 
@@ -48,6 +52,71 @@ class HashJoinResult:
     c_write: int
     per_phase_rounds: Dict[str, int]
     output_page_ids: List[int] = dataclasses.field(default_factory=list)
+
+
+class KeyIndex(NamedTuple):
+    """One build partition's table: its keys sorted once, stably, with the
+    payloads in the same order, and whether no key repeats."""
+
+    keys: np.ndarray
+    payloads: np.ndarray
+    unique: bool
+
+
+def build_index(rows: np.ndarray) -> KeyIndex:
+    """Index a build partition's ``(key, payload, ...)`` rows on column 0.
+
+    ``unique`` is read from the sorted keys (no two neighbours equal), so the
+    probe's path follows the data: a primary-key build side takes the
+    one-search path of :func:`probe_index`.
+    """
+    order = np.argsort(rows[:, 0], kind="stable")
+    keys = rows[order, 0]
+    return KeyIndex(keys, rows[order, 1], not (keys[1:] == keys[:-1]).any())
+
+
+def probe_index(index: KeyIndex, probe_rows: np.ndarray) -> np.ndarray:
+    """Equijoin ``probe_rows`` against a build partition's index on column 0.
+
+    Returns ``(key, build payload, probe payload)`` rows in probe order: the
+    same pairs as ``_block_join(build_rows, probe_rows)``, which emits them
+    in build order.  Each probe key is searched once: one ``searchsorted``
+    where the build keys are unique, a left and a right one (and a repeat
+    by the match counts) where they are not.
+    """
+    keys, pk = index.keys, probe_rows[:, 0]
+    if not len(keys) or not len(pk):
+        return np.empty((0, 3), dtype=np.int64)
+    # Searching the probe keys in ascending order lets each search start from
+    # the last one's result; the positions are scattered back to probe order.
+    order = np.argsort(pk)
+    ascending = pk[order]
+
+    def search(side: str) -> np.ndarray:
+        pos = np.empty(len(pk), dtype=np.intp)
+        pos[order] = np.searchsorted(keys, ascending, side=side)
+        return pos
+
+    if index.unique:
+        pos = search("left")
+        np.minimum(pos, len(keys) - 1, out=pos)
+        hits = np.flatnonzero(keys[pos] == pk)
+        out = np.empty((len(hits), 3), dtype=np.int64)
+        out[:, 0] = pk[hits]
+        out[:, 1] = index.payloads[pos[hits]]
+        out[:, 2] = probe_rows[hits, 1]
+        return out
+    lo = search("left")
+    counts = search("right") - lo
+    total = int(counts.sum())
+    probe_idx = np.repeat(np.arange(len(pk)), counts)
+    # Build position of each pair: its key run's start plus its rank in it.
+    build_idx = np.repeat(lo - (np.cumsum(counts) - counts), counts) + np.arange(total)
+    out = np.empty((total, 3), dtype=np.int64)
+    out[:, 0] = pk[probe_idx]
+    out[:, 1] = index.payloads[build_idx]
+    out[:, 2] = probe_rows[probe_idx, 1]
+    return out
 
 
 def ehj_output(result: HashJoinResult) -> List[int]:
@@ -98,14 +167,20 @@ def ehj(
             h = keys.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
             return ((h >> np.uint64(33)) % np.uint64(p)).astype(np.int64)
 
-    def join(build_rows: np.ndarray, probe_rows: np.ndarray) -> np.ndarray:
+    def table(blocks: List[np.ndarray]) -> KeyIndex:
+        with span("ehj.table"):
+            rows = (np.concatenate(blocks, axis=0) if blocks
+                    else np.empty((0, 2), dtype=np.int64))
+            return build_index(rows)
+
+    def join(index: KeyIndex, probe_rows: np.ndarray) -> np.ndarray:
         with span("ehj.join"):
-            matched = _block_join(build_rows, probe_rows)
+            matched = probe_index(index, probe_rows)
         count("ehj.join_calls")
         count("ehj.join_rows_out", len(matched))
         return matched
 
-    # ---- P1: partition build, build resident tables, spill the rest -------
+    # ---- P1: partition build, index resident partitions, spill the rest ---
     with span("ehj.P1"):
         t0 = sched.snapshot()
         r_r1, r_w1 = plan.p1
@@ -123,11 +198,7 @@ def ehj(
                 else:
                     resident_build[q].append(sel)
         build_pool.flush_all()
-        with span("ehj.table"):
-            resident_tables = {
-                q: (np.concatenate(v, axis=0) if v else np.empty((0, 2), dtype=np.int64))
-                for q, v in resident_build.items()
-            }
+        resident_tables = {q: table(v) for q, v in resident_build.items()}
         phase_rounds["P1"] = sched.delta(t0).c_total
 
     # ---- P2: partition probe; probe resident, stage spilled ----------------
@@ -164,10 +235,11 @@ def ehj(
             q_ids = stage_pool.pages(q)
             if not b_ids or not q_ids:
                 continue
-            b_rows = PageCursor(sched, b_ids, read_pages, prefetch=prefetch).read_all()
+            b_index = table(list(PageCursor(sched, b_ids, read_pages,
+                                            prefetch=prefetch).blocks()))
             for q_rows in PageCursor(sched, q_ids, read_pages,
                                      prefetch=prefetch).blocks():
-                matched = join(b_rows, q_rows)
+                matched = join(b_index, q_rows)
                 if len(matched):
                     output_rows += len(matched)
                     ext_out_pool.add(matched, stream=q)

@@ -5,6 +5,8 @@ data-plane algorithms matches the paper's §III analysis, and that every
 operator produces exactly the oracle output.
 """
 
+import importlib
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -17,9 +19,13 @@ from repro.remote import (
     RemoteMemory, bnlj, bnlj_oracle, ehj, ehj_oracle, ems_sort, ems_oracle,
     make_relation,
 )
-from repro.remote.simulator import make_key_pages
+from repro.remote.bnlj import _block_join
+from repro.remote.ehj import build_index, probe_index
+from repro.remote.simulator import Relation, make_key_pages, relation_rows
 
 TIER = TESTBED["remon_tcp"]
+# The module, not the function ``repro.remote`` exports under its name.
+ehj_mod = importlib.import_module("repro.remote.ehj")
 
 
 def _mk():
@@ -202,6 +208,86 @@ def test_ehj_correct_for_any_plan(sigma, parts, seed):
     plan = ehj_plan(b=48, q=96, out=36, m_b=12, partitions=parts, sigma=sigma)
     res = ehj(remote, build, probe, plan)
     assert res.output_rows == ehj_oracle(remote, build, probe)
+
+
+def _multiset(rows: np.ndarray) -> np.ndarray:
+    """Rows as a sorted array of tuples: equal iff equal as multisets."""
+    return np.sort(np.ascontiguousarray(rows, dtype=np.int64).view("i8,i8,i8").ravel())
+
+
+def _rows(keys) -> np.ndarray:
+    keys = np.asarray(keys, dtype=np.int64)
+    return np.stack([keys, 1000 + np.arange(len(keys), dtype=np.int64)], axis=1)
+
+
+_rng = np.random.default_rng(7)
+INDEX_CASES = {
+    "unique": (_rows(_rng.permutation(300)[:200]), _rows(_rng.integers(0, 300, 700))),
+    "duplicate": (_rows(_rng.integers(0, 40, 200)), _rows(_rng.integers(0, 50, 300))),
+    "no_matches": (_rows(np.arange(0, 100)), _rows(np.arange(100, 180))),
+    "empty_build": (_rows([]), _rows(np.arange(10))),
+    "empty_probe": (_rows(np.arange(10)), _rows([])),
+    "negative": (_rows(_rng.integers(-30, 30, 150)), _rows(_rng.integers(-40, 40, 400))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INDEX_CASES))
+def test_probe_index_matches_block_join(case):
+    build, probe = INDEX_CASES[case]
+    index = build_index(build)
+    keys = build[:, 0]
+    assert index.unique == (len(np.unique(keys)) == len(keys))
+    assert (np.diff(index.keys) >= 0).all()
+    got, want = probe_index(index, probe), _block_join(build, probe)
+    assert got.dtype == np.int64 and got.shape[1:] == (3,)
+    assert len(got) == len(want)
+    assert (_multiset(got) == _multiset(want)).all()
+
+
+def _relation(remote, keys, rows_per_page: int) -> Relation:
+    rows = _rows(keys)
+    pages = [rows[i:i + rows_per_page] for i in range(0, len(rows), rows_per_page)]
+    return Relation(page_ids=remote.put_local(pages), rows_per_page=rows_per_page,
+                    total_rows=len(rows))
+
+
+def _ehj_inputs(remote, unique: bool, seed: int):
+    """Build and probe relations of 8-row pages: a primary-key build side
+    (and probe keys, some matching none) or both sides drawn from a small
+    key domain, so build keys repeat."""
+    rng = np.random.default_rng(seed)
+    if unique:
+        build_keys = rng.permutation(1024)[:48 * 8] - 200
+        probe_keys = rng.integers(-200, 900, 96 * 8)
+    else:
+        build_keys = rng.integers(-20, 100, 48 * 8)
+        probe_keys = rng.integers(-20, 120, 96 * 8)
+    return _relation(remote, build_keys, 8), _relation(remote, probe_keys, 8)
+
+
+@pytest.mark.parametrize("unique", [True, False], ids=["unique", "duplicate"])
+@pytest.mark.parametrize("sigma", [0.0, 0.5, 1.0])
+def test_ehj_output_is_the_block_join_with_the_same_rounds(monkeypatch, sigma, unique):
+    """The index-probing EHJ emits every matching pair once, and moves the
+    same pages in the same rounds as the EHJ that block-joined each call."""
+    plan = ehj_plan(b=48, q=96, out=36, m_b=12, partitions=8, sigma=sigma)
+    remote = _mk()
+    build, probe = _ehj_inputs(remote, unique, seed=int(sigma * 10) + unique)
+    res = ehj(remote, build, probe, plan)
+    out = relation_rows(remote, Relation(res.output_page_ids, 8, res.output_rows))
+    want = _block_join(relation_rows(remote, build), relation_rows(remote, probe))
+    assert res.output_rows == len(out) == len(want) > 0
+    assert (_multiset(out) == _multiset(want)).all()
+
+    monkeypatch.setattr(ehj_mod, "build_index", lambda rows: rows)
+    monkeypatch.setattr(ehj_mod, "probe_index", _block_join)
+    remote = _mk()
+    build, probe = _ehj_inputs(remote, unique, seed=int(sigma * 10) + unique)
+    blocked = ehj(remote, build, probe, plan)
+    assert res.per_phase_rounds == blocked.per_phase_rounds
+    assert (res.c_read, res.c_write) == (blocked.c_read, blocked.c_write)
+    assert len(res.output_page_ids) == len(blocked.output_page_ids)
+    assert res.output_rows == blocked.output_rows
 
 
 def test_ehj_remop_pools_reduce_write_rounds():

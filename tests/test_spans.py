@@ -3,12 +3,18 @@ nests spans with their self seconds, counts, and lands on the profiler's
 host plane where the benchmark's trace reduction names idle time by it."""
 
 import glob
+import importlib
 import os
 import time
 
+import numpy as np
 import pytest
 
 from repro import spans
+from repro.core import TESTBED
+from repro.core.policies import ehj_plan
+from repro.remote import RemoteMemory
+from repro.remote.simulator import Relation
 
 
 @pytest.fixture(autouse=True)
@@ -136,3 +142,51 @@ def test_program_spans_land_on_the_profiler_host_plane(tmp_path):
     assert {"ehj.join", "hook.device", "query", "window"} <= named
     join = [b - a for a, b, n in segs if n == "ehj.join"]
     assert sum(join) == pytest.approx(events["ehj.join"][1] - events["ehj.join"][0])
+
+
+@pytest.mark.parametrize("unique", [True, False], ids=["unique", "duplicate"])
+def test_the_hash_join_indexes_each_partition_once(monkeypatch, unique):
+    """Traced, the external hash join opens one ``ehj.table`` span per build
+    partition it indexes (the resident ones in P1, each spilled one in P3),
+    probes every probe row once, and takes the one-search path exactly where
+    the build keys are unique."""
+    ehj = importlib.import_module("repro.remote.ehj")
+    indexed, probed = [], []
+    build_index, probe_index = ehj.build_index, ehj.probe_index
+
+    def counted_build(rows):
+        index = build_index(rows)
+        indexed.append(index.unique)
+        return index
+
+    def counted_probe(index, rows):
+        probed.append(len(rows))
+        return probe_index(index, rows)
+
+    monkeypatch.setattr(ehj, "build_index", counted_build)
+    monkeypatch.setattr(ehj, "probe_index", counted_probe)
+
+    rng = np.random.default_rng(5)
+    n_build, n_probe, rows = 512, 2048, 16
+    build_keys = rng.permutation(4096)[:n_build] if unique else rng.integers(0, 64, n_build)
+    probe_keys = rng.integers(0, 4096 if unique else 64, n_probe)
+    remote = RemoteMemory(TESTBED["remon_tcp"])
+
+    def relation(keys):
+        t = np.stack([keys, np.arange(len(keys))], axis=1).astype(np.int64)
+        return Relation(remote.put_local([t[i:i + rows] for i in range(0, len(t), rows)]),
+                        rows, len(t))
+
+    build, probe = relation(build_keys), relation(probe_keys)
+    plan = ehj_plan(b=n_build / rows, q=n_probe / rows, out=n_probe / rows, m_b=12,
+                    partitions=8, sigma=0.5)
+    spans.enable()
+    with spans.Recorder(0) as r:
+        res = ehj.ehj(remote, build, probe, plan)
+    # Every partition of both sides holds rows at this size: 4 indexed in
+    # P1, 4 in P3, and every probe row reaches one of them.
+    assert r.totals["ehj.table"].calls == len(indexed) == 8
+    assert sum(indexed) == (8 if unique else 0)
+    assert sum(probed) == n_probe
+    assert r.counts["ehj.join_calls"] == r.totals["ehj.join"].calls == len(probed)
+    assert r.counts["ehj.join_rows_out"] == res.output_rows > 0
