@@ -4,19 +4,32 @@ The reference is numpy over the tables the benchmark generated, and imports
 nothing of the program.  It follows the task graph the planner chose (which
 input a join builds on, which join runs first), and computes each task's
 output from its inputs: base tables as generated, and upstream tasks as the
-reference computed them, never as the program did.
+reference computed them, never as the program did.  What the query computes
+(key columns, predicates, constants) the reference takes from the cell's
+configuration or its own query module, never from the plan: a planner that
+lowered the wrong column would otherwise be followed by the reference.
+
+Per operator there are three entries: ``REFERENCE`` (a function of the
+task's inputs and the cell's configuration, returning rows), ``CHECKS`` (the name of the
+number compared) and ``COMPARE`` (the function that counts what differs).
+This module holds the shared ones:
 
 * EHJ: the equijoin on column 0, as ``(key, build payload, probe payload)``
   rows, compared as a multiset;
 * EMS: every value of every input page, sorted, compared in exact order.
 
-Each comparison gives a count of rows (keys) that differ, and the limit of
-every count is 0: the configurations state exact results.
+A query module (``queries/<query>.py``) may define any of the three dicts
+itself; ``rules(query)`` merges them over the shared ones, the query's entry
+winning for an op it names.  Each comparison gives a count of rows (keys)
+that differ, and the limit of every count is 0: the configurations state
+exact results.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Sequence, Tuple
+import dataclasses
+import pathlib
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -43,8 +56,8 @@ def sort(values: np.ndarray) -> np.ndarray:
 
 
 REFERENCE: Dict[str, Callable[..., np.ndarray]] = {
-    "ehj": lambda ins: join(ins["build"], ins["probe"]),
-    "ems": lambda ins: sort(ins["page_ids"]),
+    "ehj": lambda ins, config: join(ins["build"], ins["probe"]),
+    "ems": lambda ins, config: sort(ins["page_ids"]),
 }
 
 
@@ -109,23 +122,60 @@ COMPARE = {"ehj": rows_differing, "ems": keys_differing}
 Task = Tuple[str, Dict[str, Tuple[str, object]]]
 
 
-def reference(structure: Sequence[Task], tables: Dict[str, np.ndarray],
-              impl: Dict[str, Callable[..., np.ndarray]] = REFERENCE
-              ) -> List[np.ndarray]:
-    """Every task's reference output, in task order."""
-    out: List[np.ndarray] = []
-    for op, inputs in structure:
-        ins = {name: tables[src] if kind == "table" else out[src]
-               for name, (kind, src) in inputs.items()}
-        out.append(impl[op](ins))
-    return out
+@dataclasses.dataclass(frozen=True)
+class Rules:
+    """One cell's entries per operator: ``reference``, ``checks`` and
+    ``compare``; ``source``, the query file they were merged from; and
+    ``config``, the cell's configuration, which every reference is given."""
+
+    reference: Dict[str, Callable[..., np.ndarray]]
+    checks: Dict[str, str]
+    compare: Dict[str, Callable[[np.ndarray, np.ndarray], int]]
+    source: str
+    config: dict
+
+    def require(self, structure: Sequence[Task]) -> None:
+        """Fail, naming the op and the query file, where a task's op lacks
+        an entry."""
+        for op, _ in structure:
+            for entry in ("REFERENCE", "CHECKS", "COMPARE"):
+                if op not in getattr(self, entry.lower()):
+                    raise ValueError(
+                        f"no {entry}[{op!r}] for the op {op!r}: neither {self.source} "
+                        f"nor chipbench/check.py defines it")
+
+    def outputs(self, structure: Sequence[Task], tables: Dict[str, np.ndarray],
+                impl: Optional[Dict[str, Callable[..., np.ndarray]]] = None
+                ) -> List[np.ndarray]:
+        """Every task's output by ``impl`` (the reference unless given), in
+        task order."""
+        self.require(structure)
+        impl = self.reference if impl is None else impl
+        out: List[np.ndarray] = []
+        for op, inputs in structure:
+            ins = {name: tables[src] if kind == "table" else out[src]
+                   for name, (kind, src) in inputs.items()}
+            out.append(impl[op](ins, self.config))
+        return out
+
+    def count(self, structure: Sequence[Task], got: Sequence[np.ndarray],
+              want: Sequence[np.ndarray]) -> Dict[str, int]:
+        """The worst count per check over the tasks of one query."""
+        counts: Dict[str, int] = {}
+        for (op, _), g, w in zip(structure, got, want, strict=True):
+            name = self.checks[op]
+            counts[name] = max(counts.get(name, 0), self.compare[op](g, w))
+        return counts
 
 
-def compare(structure: Sequence[Task], got: Sequence[np.ndarray],
-            want: Sequence[np.ndarray]) -> Dict[str, int]:
-    """The worst count per check over the tasks of one query."""
-    counts: Dict[str, int] = {}
-    for (op, _), g, w in zip(structure, got, want, strict=True):
-        name = CHECKS[op]
-        counts[name] = max(counts.get(name, 0), COMPARE[op](g, w))
-    return counts
+def rules(query=None, config: Optional[dict] = None) -> Rules:
+    """The shared entries, with the query module's own ``REFERENCE``,
+    ``CHECKS`` and ``COMPARE`` merged over them, for a cell of the
+    configuration ``config``."""
+    own = {name: getattr(query, name, {}) for name in ("REFERENCE", "CHECKS", "COMPARE")}
+    source = getattr(query, "__file__", None)
+    return Rules(reference={**REFERENCE, **own["REFERENCE"]},
+                 checks={**CHECKS, **own["CHECKS"]},
+                 compare={**COMPARE, **own["COMPARE"]},
+                 source=f"queries/{pathlib.Path(source).name}" if source else "no query file",
+                 config=dict(config or {}))

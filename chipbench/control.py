@@ -11,7 +11,10 @@ the program's place: the reference again, over the same task graph, but
 breaking one guarantee the configuration states, and the same comparison
 reads it.  A limit has to lie between the two readings.
 
-The controls:
+The reference, the check names and the comparisons are the cell's
+(``check.rules``: a query module's own entries merged over the shared ones).
+An op with no control keeps its reference, so a control breaks the ops it
+names and the tasks downstream of them.  The controls:
 
 * EHJ keeps one output row per key, as a hash table with unique keys would:
   it breaks the multiset (bag) semantics of the join.
@@ -47,8 +50,8 @@ def sort_float32(values: np.ndarray) -> np.ndarray:
 
 
 CONTROL = {
-    "ehj": lambda ins: join_unique_keys(ins["build"], ins["probe"]),
-    "ems": lambda ins: sort_float32(ins["page_ids"]),
+    "ehj": lambda ins, config: join_unique_keys(ins["build"], ins["probe"]),
+    "ems": lambda ins, config: sort_float32(ins["page_ids"]),
 }
 
 
@@ -63,15 +66,15 @@ def readings(cell, seed: int, *, log) -> Dict[str, Dict[str, int]]:
                              for t in cell.config["tiers"]])
     inputs = cell.query.place(backend, tables, cell.config)
     keep = set(backend.resident_ids())
-    rec, result, outputs = harness.run_query(backend, cell, inputs, keep,
-                                             harness.Spans(annotate=False), False)
+    rec, result, outputs = harness.run_query(backend, cell, inputs, keep, harness.Spans())
     struct = harness.structure(result, inputs)
     del backend, inputs, result
     got = [np.concatenate(pages, axis=0) for pages in outputs]
-    want = check.reference(struct, tables)
-    control = check.reference(struct, tables, CONTROL)
-    out = {"program": check.compare(struct, got, want),
-           "control": check.compare(struct, control, want)}
+    rules = check.rules(cell.query, cell.config)
+    want = rules.outputs(struct, tables)
+    control = rules.outputs(struct, tables, {**rules.reference, **CONTROL})
+    out = {"program": rules.count(struct, got, want),
+           "control": rules.count(struct, control, want)}
     log(f"seed {seed}: query {json.dumps(rec)}; "
         f"program {json.dumps(out['program'])}; control {json.dumps(out['control'])}")
     return out

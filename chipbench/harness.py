@@ -15,9 +15,12 @@ query, which compiles (or loads from the persistent cache) exactly the
 programs the window's queries run.
 
 After the window the outputs of a sample of its queries, drawn from the
-seed, are compared with the numpy reference (``check.py``).  With ``trace``
-the window runs under the JAX profiler, with host spans around each layer
-the query crosses, and the per-layer metrics are read from the record.
+seed, are compared with the numpy reference (``check.py``, with the query
+module's own entries merged over its shared ones).  With ``trace`` the
+window runs under the JAX profiler with the program's tracer
+(``repro.spans``) on and annotated, so every query records the program's
+spans and counters, and the per-layer metrics are read from the record.
+Without it the tracer stays off.
 """
 
 from __future__ import annotations
@@ -35,7 +38,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from chipbench import check, trace as trace_mod, work
+from chipbench import check, program_spans, trace as trace_mod, work
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -89,78 +92,72 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     )
 
 
+def reader(here: pathlib.Path, name: str):
+    """A metric's reader, ``metrics/<name>.py``.  A name ``<reader>.<part>``
+    with no file of its own is read by ``metrics/<reader>.py``: a later cell
+    that runs what an existing reader reads brings an entry of its own that
+    lists it (``join_s.q3``), and no file or entry that is there changes."""
+    path = here / "metrics" / f"{name}.py"
+    if not path.is_file() and "." in name:
+        path = here / "metrics" / f"{name.split('.', 1)[0]}.py"
+    return work.load_module(path)
+
+
 def read_metrics(metrics: List[dict], record: "Record") -> Dict[str, dict]:
-    """Run each metric's reader, ``metrics/<name>.py``; a reader that finds
-    nothing to read returns None and the metric is left out."""
+    """Run each metric's reader; a reader that finds nothing to read returns
+    None and the metric is left out."""
     out = {}
     for m in metrics:
-        value = work.load_module(record.here / "metrics" / f"{m['name']}.py").read(record)
+        value = reader(record.here, m["name"]).read(record)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out
 
 
 # --------------------------------------------------------------------------
-# Host spans and hook instrumentation
+# Host spans and hook-call shapes
 # --------------------------------------------------------------------------
 
 
 class Spans:
-    """Host-clock seconds per span name; with ``annotate`` each span is also
-    a ``jax.profiler.TraceAnnotation`` in the trace."""
+    """Host-clock seconds per span name; each span is also the program's
+    ``repro.spans.span``, a no-op unless its tracer is on."""
 
-    def __init__(self, annotate: bool):
-        self.annotate = annotate
+    def __init__(self):
+        from repro import spans
+
+        self._program = spans.span
         self.seconds: Dict[str, float] = {}
 
     @contextlib.contextmanager
     def __call__(self, name: str):
         t0 = time.perf_counter()
         try:
-            if self.annotate:
-                import jax
-
-                with jax.profiler.TraceAnnotation(name):
-                    yield
-            else:
+            with self._program(name):
                 yield
         finally:
             self.seconds[name] = self.seconds.get(name, 0.0) + time.perf_counter() - t0
 
 
-def _wrap(obj, attr: str, span: Spans, name: str, after: Optional[Callable] = None) -> None:
-    inner = getattr(obj, attr)
-
-    def wrapped(*args, **kwargs):
-        with span(name):
-            out = inner(*args, **kwargs)
-        if after is not None:
-            after(*args)
-        return out
-
-    setattr(obj, attr, wrapped)
-
-
-def instrument(backend, span: Spans, calls: Dict[str, list]) -> None:
-    """Spans around the backend's hooks and tier rounds, and the size of
-    every hook call that ran a kernel (``n`` rows, ``d`` columns)."""
+def instrument(backend, calls: Dict[str, list]) -> None:
+    """Record the size of every hook call that ran a kernel (``n`` rows,
+    ``d`` columns), which the roofline readers need."""
     wall = backend.wall
-    seen = [wall.kernel_calls]
 
-    def record(hook: str, shape: Callable) -> Callable:
-        def after(*args):
-            if wall.kernel_calls > seen[0]:
+    def wrap(hook: str, shape: Callable) -> None:
+        inner = getattr(backend, hook)
+
+        def wrapped(*args, **kwargs):
+            seen = wall.kernel_calls
+            out = inner(*args, **kwargs)
+            if wall.kernel_calls > seen:
                 calls.setdefault(hook, []).append(shape(*args))
-            seen[0] = wall.kernel_calls
-        return after
+            return out
 
-    _wrap(backend, "sort_keys", span, "hook.sort_keys",
-          record("sort_keys", lambda keys: {"n": len(keys)}))
-    _wrap(backend, "partition_rows", span, "hook.partition_rows",
-          record("partition_rows", lambda rows, parts: {"n": len(rows), "d": rows.shape[1]}))
-    for tier in backend.tiers:
-        _wrap(tier, "read_batch", span, "tier.read")
-        _wrap(tier, "write_batch", span, "tier.write")
+        setattr(backend, hook, wrapped)
+
+    wrap("sort_keys", lambda keys: {"n": len(keys)})
+    wrap("partition_rows", lambda rows, parts: {"n": len(rows), "d": rows.shape[1]})
 
 
 # --------------------------------------------------------------------------
@@ -169,7 +166,8 @@ def instrument(backend, span: Spans, calls: Dict[str, list]) -> None:
 
 
 def structure(result, inputs: Dict[str, object]) -> List[check.Task]:
-    """The task graph a query ran, as the check sees it."""
+    """The task graph a query ran, as the check sees it: each task's op and
+    its inputs."""
     from repro.engine.session import TaskOutput
 
     table_of = {id(v): k for k, v in inputs.items()}
@@ -184,8 +182,7 @@ def structure(result, inputs: Dict[str, object]) -> List[check.Task]:
     return out
 
 
-def run_query(backend, cell: Cell, inputs: Dict[str, object], keep: set,
-              span: Spans, annotate_tasks: bool):
+def run_query(backend, cell: Cell, inputs: Dict[str, object], keep: set, span: Spans):
     """One query: a fresh ``Session``, the query, and every page it created
     freed.  Returns (its record, its result, its output pages).
 
@@ -202,14 +199,6 @@ def run_query(backend, cell: Cell, inputs: Dict[str, object], keep: set,
     start = time.perf_counter()
     with span("query"):
         session = Session(backend, budget=cell.traffic["budget_pages"])
-        if annotate_tasks:
-            inner = session.exec_task
-
-            def exec_task(task, *args, **kwargs):
-                with span(f"task.{task.op}"):
-                    return inner(task, *args, **kwargs)
-
-            session.exec_task = exec_task
         result = cell.query.run(session, inputs, cell.config, cell.params, span)
         outputs = [backend.peek_batch(get(tr.op).output_of(tr.result))
                    for tr in result.per_task]
@@ -268,8 +257,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *, jax,
     inputs = cell.query.place(backend, tables, cell.config)
     keep = set(backend.resident_ids())
     t_data = time.perf_counter()
-    quiet = Spans(annotate=False)
-    warm, warm_result, _ = run_query(backend, cell, inputs, keep, quiet, False)
+    warm, warm_result, _ = run_query(backend, cell, inputs, keep, Spans())
+    rules = check.rules(cell.query, cell.config)
+    rules.require(structure(warm_result, inputs))
     t_warm = time.perf_counter()
     setup_s = t_warm - process_start
     log(f"device: {device.platform} {device.device_kind} x{len(jax.devices())}; "
@@ -281,14 +271,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *, jax,
         + ", ".join(f"{k} {v:.6f} s" for k, v in compiles.seconds.items()))
     log(f"warm query: {json.dumps(warm)}")
 
-    span = Spans(annotate=trace)
+    span = Spans()
     hook_calls: Dict[str, list] = {}
     log_dir = None
     if trace:
-        instrument(backend, span, hook_calls)
+        from repro import spans
+
+        instrument(backend, hook_calls)
         log_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
+        spans.enable(annotate=True)
         jax.profiler.start_trace(log_dir, profiler_options=options)
 
     rng = np.random.default_rng([seed, 0x5eed])
@@ -301,7 +294,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *, jax,
             t0 = time.perf_counter()
             deadline = t0 + seconds
             while True:
-                rec, result, outputs = run_query(backend, cell, inputs, keep, span, trace)
+                # With the tracer on, the record gains the program's spans
+                # and counters.
+                with (program_spans.recording(backend, len(queries)) if trace
+                      else contextlib.nullcontext({})) as fields:
+                    rec, result, outputs = run_query(backend, cell, inputs, keep, span)
+                rec.update(fields)
                 queries.append(rec)
                 i = len(queries)
                 slot = i - 1 if i <= sample_size else int(rng.integers(0, i))
@@ -317,6 +315,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *, jax,
     finally:
         if trace:
             jax.profiler.stop_trace()
+            spans.disable()
     window_compiles = compiles.requests - requests0
     stats = device.memory_stats() or {}
     memory_peak = int(stats.get("peak_bytes_in_use", 0))
@@ -335,10 +334,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *, jax,
     for qi, struct, outputs in sorted(sampled, key=lambda e: e[0]):
         key = json.dumps(struct)
         if key not in want_by_structure:
-            want_by_structure[key] = check.reference(struct, tables)
+            want_by_structure[key] = rules.outputs(struct, tables)
         got = [np.concatenate(pages, axis=0) if pages else np.empty((0,), np.int64)
                for pages in outputs]
-        c = check.compare(struct, got, want_by_structure[key])
+        c = rules.count(struct, got, want_by_structure[key])
         if any(v > check.LIMIT for v in c.values()):
             failed += 1
         for name, v in c.items():
@@ -375,7 +374,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *, jax,
         metrics = read_metrics(cell.end_to_end, record)
 
     checks = {name: {"value": counts.get(name, 0), "limit": check.LIMIT}
-              for name in sorted({check.CHECKS[op] for struct in want_by_structure
+              for name in sorted({rules.checks[op] for struct in want_by_structure
                                   for op, _ in json.loads(struct)})}
     correct = bool(sampled) and failed == 0 and all(
         c["value"] <= c["limit"] for c in checks.values())

@@ -11,9 +11,9 @@ from __future__ import annotations
 import contextlib
 from typing import Dict, Iterable, Optional
 
-# The readers of the program's spans and their units.  They have no
-# ``per_layer`` entry in BENCHMARK.json yet: the harness's traced run does
-# not switch the program's tracer on.
+# The readers of the program's spans and their units, each a ``per_layer``
+# entry in BENCHMARK.json: the harness's traced run switches the program's
+# tracer on, and its untraced run leaves it off, so they read nothing there.
 METRICS: Dict[str, str] = {"join_s": "s", "hash_s": "s", "buffer_s": "s",
                            "hook_host_s": "s", "tier_host_s": "s", "pad_share": "%"}
 

@@ -20,10 +20,12 @@ def root(tmp_path_factory):
 
     root = tiny.copy(tmp_path_factory.mktemp("bench"), jax.devices()[0].device_kind)
     tiny.add_sort_cell(root)  # the sort's check, for a later sort cell
+    tiny.add_join_agg_cell(root)  # a query's own reference, merged as the harness does
     return root
 
 
-@pytest.mark.parametrize("name", ["pkfk-spill", "pkfk-inmem", "sort-2k-spill"])
+@pytest.mark.parametrize("name", ["pkfk-spill", "pkfk-inmem", "sort-2k-spill",
+                                  "join_agg-spill"])
 def test_program_reads_zero_and_the_control_fails(root, name):
     r = control.readings(harness.load_cell(name, root), SEED, log=lambda s: None)
     assert r["program"] and all(v == 0 for v in r["program"].values())
@@ -37,7 +39,7 @@ def test_controls_break_what_they_claim():
     probe = np.array([[1, 100], [1, 101], [3, 300]], np.int64)
     for b, p in ((build, probe), (probe, build)):  # whichever side repeats keys
         assert len(control.join_unique_keys(b, p)) == 1
-        assert len(control.CONTROL["ehj"]({"build": b, "probe": p})) == 1
+        assert len(control.CONTROL["ehj"]({"build": b, "probe": p}, {})) == 1
 
 
 _sort, _part = ExecutionBackend.sort_keys, ExecutionBackend.partition_rows

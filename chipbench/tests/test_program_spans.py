@@ -3,10 +3,13 @@ kernels interpreted): tracing leaves every output and every ledger count as
 it was, each layer's spans appear, and the readers of the spans find them in
 a traced record and nothing in an untraced one."""
 
+import json
+
 import pytest
 
-from chipbench import harness, program_spans, spanrun, work
+from chipbench import harness, program_spans, work
 from chipbench.tests import tiny
+from chipbench.tests.test_rehearsal import rehearse
 from repro import spans
 
 SEED = 2**31 + 11
@@ -54,15 +57,16 @@ def _query(cell, traced: bool):
     """One query on a fresh backend: (record, result, outputs, backend)."""
     backend, inputs, keep = _setup(cell)
     if not traced:
-        rec, result, outputs = harness.run_query(backend, cell, inputs, keep,
-                                                 harness.Spans(annotate=False), False)
+        rec, result, outputs = harness.run_query(backend, cell, inputs, keep, harness.Spans())
         return rec, result, outputs, backend
     spans.enable()
     try:
-        rec, result, outputs = spanrun.run_query(backend, cell, inputs, keep,
-                                                 spanrun.Tracer(), 0)
+        with program_spans.recording(backend, 0) as fields:
+            rec, result, outputs = harness.run_query(backend, cell, inputs, keep,
+                                                     harness.Spans())
     finally:
         spans.disable()
+    rec.update(fields)
     return rec, result, outputs, backend
 
 
@@ -87,7 +91,7 @@ def test_tracing_changes_no_output_and_no_ledger_count(root, name):
 def test_every_layer_of_the_spilling_join_has_its_spans(root):
     rec, result, _, _ = _query(harness.load_cell("pkfk-spill", root), traced=True)
     assert JOIN_SPANS <= set(rec["spans"])
-    assert JOIN_COUNTS == set(rec["counts"])
+    assert JOIN_COUNTS <= set(rec["counts"])
     assert rec["spans"]["task.ehj"]["calls"] == 1
     assert all(rec["spans"][f"ehj.P{i}"]["calls"] == 1 for i in (1, 2, 3))
     assert rec["counts"]["ehj.join_calls"] == rec["spans"]["ehj.join"]["calls"]
@@ -135,12 +139,12 @@ def test_span_readers_read_a_traced_record_and_nothing_else(root):
     assert 0.8 * traced["run_s"] <= covered <= traced["run_s"]
 
 
-
-def test_spanrun_reads_the_program_spans_of_a_profiled_window(root, monkeypatch):
-    """The CPU's trace has no device plane, so the reduction is replaced by
-    one that checks the host plane and names the idle time by its spans."""
-    import jax
-
+def test_the_traced_window_reads_the_program_spans_and_the_untraced_none(root, monkeypatch):
+    """``run_cell`` with ``trace`` switches the program's tracer on for its
+    window: every query's record carries its spans and counters, and the
+    six readers of them report.  The CPU's trace has no device plane, so the
+    reduction is replaced by one that checks the host plane and names the
+    idle time by its spans."""
     from chipbench import trace
 
     host_names = set()
@@ -156,13 +160,21 @@ def test_spanrun_reads_the_program_spans_of_a_profiled_window(root, monkeypatch)
                                clock_shift_s=0.0)
 
     monkeypatch.setattr(trace, "reduce", reduce)
-    line = spanrun.measure(harness.load_cell("pkfk-spill", root), SEED, 0.0, jax=jax,
-                           log=lambda s: None)
-    assert line["attempted"] == 1 and line["spans_per_query"] > 0
+    traced, traced_lines = rehearse(root, "pkfk-spill", seconds=0.0, trace=True)
+    assert spans.span("after") is spans.span("the window")  # the tracer is off again
+    untraced, untraced_lines = rehearse(root, "pkfk-spill", seconds=0.0)
+
+    def queries(lines):
+        return [json.loads(s.split(": ", 1)[1]) for s in lines if s.startswith("query ")]
+
+    assert traced["correct"] is True and untraced["correct"] is True
+    assert all(q["spans"] and JOIN_COUNTS <= set(q["counts"]) for q in queries(traced_lines))
+    assert all("spans" not in q and "counts" not in q for q in queries(untraced_lines))
     # The benchmark's spans and the program's share the window's host thread.
-    assert {"window", "query", "session.run", "task.ehj", "ehj.join"} <= host_names
-    assert set(SPAN_METRICS) <= set(line["metrics"])
-    assert not spanrun.UNREAD & set(line["metrics"])
-    assert {"host_op_s", "hook_s", "transfer_s", "rounds"} <= set(line["metrics"])
-    assert line["metrics"]["device_idle"]["value"] == pytest.approx(75.0)
-    assert line["self_s"]["ehj.join"] == pytest.approx(line["metrics"]["join_s"]["value"])
+    assert {"window", "query", "session.run", "task.ehj", "ehj.join", "tier.write",
+            "hook.partition_rows"} <= host_names
+    assert set(SPAN_METRICS) <= set(traced["metrics"])
+    assert {"host_op_s", "hook_s", "transfer_s", "rounds", "plan_s"} <= set(traced["metrics"])
+    assert traced["metrics"]["device_idle"]["value"] == pytest.approx(75.0)
+    assert traced["breakdown"]["idle_gaps"] == [["ehj.join", 0.5]]
+    assert set(untraced["metrics"]) == {"query_s", "setup_s"}

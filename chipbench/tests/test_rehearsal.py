@@ -25,13 +25,13 @@ def root(tmp_path_factory):
     return tiny.copy(tmp_path_factory.mktemp("bench"), jax.devices()[0].device_kind)
 
 
-def rehearse(root, name, seed=SEED, seconds=0.5):
+def rehearse(root, name, seed=SEED, seconds=0.5, trace=False):
     import jax
 
     lines = []
     counter = CompileCounter(jax)
     try:
-        line = harness.run_cell(harness.load_cell(name, root), seed, seconds, False,
+        line = harness.run_cell(harness.load_cell(name, root), seed, seconds, trace,
                                 jax=jax, compiles=counter,
                                 process_start=time.perf_counter(), log=lines.append)
     finally:

@@ -16,6 +16,8 @@ import shutil
 REPO = pathlib.Path(__file__).resolve().parents[2]
 
 SIZES = {"blanas11-pkfk": dict(build_rows=256, probe_rows=4096, page_rows=64)}
+QUERIES = pathlib.Path(__file__).resolve().parent / "data" / "queries"
+
 
 def _edit(path: pathlib.Path, **changes) -> None:
     data = json.loads(path.read_text())
@@ -56,3 +58,29 @@ def add_sort_cell(root: pathlib.Path) -> str:
                                "moves": "query_s", "workloads": ["sort-2k-spill"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return "sort-2k-spill"
+
+
+def add_join_agg_cell(root: pathlib.Path, reference: bool = True) -> str:
+    """Add a cell whose query brings its own reference (``data/queries/
+    join_agg.py``: an EHJ, then an EAGG of its output) to a copy, as new
+    files and entries alone, under the spilling join's traffic.  Without
+    ``reference`` the query file (``join_agg_noref.py``) defines no
+    reference for its aggregation.  Returns the cell's name."""
+    here = root / "chipbench"
+    query = "join_agg" if reference else "join_agg_noref"
+    text = (QUERIES / "join_agg.py").read_text()
+    if not reference:
+        text += "\ndel REFERENCE\n"
+    (here / "queries" / f"{query}.py").write_text(text)
+    (here / "configs" / f"{query}.json").write_text(json.dumps(
+        {"name": query, "query": query, "tiers": ["remon_tcp"], "plan": {"partitions": 8},
+         **SIZES["blanas11-pkfk"]}))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": query, "source": "a test",
+                             "file": f"chipbench/configs/{query}.json",
+                             "reduced": [], "why": "a test"})
+    name = f"{query}-spill"
+    bench["workloads"].append({"name": name, "config": query,
+                               "traffic": "closed1-b8-s0.5", "chips": 1, "why": "a test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return name
