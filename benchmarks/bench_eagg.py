@@ -14,7 +14,7 @@ from repro.core import TABLE_I
 from repro.core.policies import eagg_costs_exact
 from repro.engine import WorkloadStats, plan_operator, registry
 from repro.remote import RemoteMemory, make_relation
-from repro.remote.eagg import _hash_part
+from repro.remote.rows import partition_of
 from benchmarks.common import Row, timed
 
 TIER = TABLE_I["tcp"]  # paper Table I constants (see bench_bnlj)
@@ -31,7 +31,7 @@ def _run(plan, seed=0):
 
 def _exact_parity(remote, rel, plan, res) -> bool:
     rows = np.concatenate(remote.peek_batch(rel.page_ids), axis=0)
-    parts = _hash_part(rows[:, 0], plan.partitions)
+    parts = partition_of(rows[:, 0], plan.partitions)
     n_spilled = int(round(plan.sigma * plan.partitions))
     spilled = list(range(plan.partitions - n_spilled, plan.partitions))
     spill_mask = np.isin(parts, spilled)

@@ -287,6 +287,41 @@ def ems_total_latency(n: float, m: float, plan: EMSPlan, tau: float) -> float:
     return d + tau * c
 
 
+def ems_limit_costs(
+    n: int, m: int, k: int, r_in_pages: int, r_out_pages: int, limit_pages: int
+) -> Tuple[float, float, int]:
+    """Exact (D, C, passes) of a whole sort that keeps only the first
+    ``limit_pages`` pages of its order (ORDER BY ... LIMIT), run formation
+    included, over ``n`` full input pages.
+
+    Run formation reads each M-page chunk in one round and writes its first
+    ``min(chunk, limit_pages)`` pages in one.  A merge of ``g`` runs reads
+    each run in one refill of its ``floor(R_in/g)``-page buffer and writes
+    ``min(sum of runs, limit_pages)`` pages through R_out, where its emitted
+    rows reach the limit and it stops.  That holds while every run fits its
+    buffer, ``limit_pages <= max(1, r_in_pages // k)``, as a LIMIT's runs
+    do; it is what :func:`repro.remote.ems.ems_sort` moves with ``limit``.
+    """
+    runs = [min(m, n - s, limit_pages) for s in range(0, n, max(m, 1))]
+    d = float(n + sum(runs))
+    c = 2.0 * len(runs)
+    passes = 0
+    while len(runs) > 1:
+        merged: List[int] = []
+        for g in range(0, len(runs), k):
+            group = runs[g:g + k]
+            if len(group) == 1:
+                merged.extend(group)
+                continue
+            out = min(sum(group), limit_pages)
+            d += sum(group) + out
+            c += len(group) + math.ceil(out / max(r_out_pages, 1))
+            merged.append(out)
+        runs = merged
+        passes += 1
+    return d, c, passes
+
+
 def ems_h(k: float, a: float) -> float:
     """h(k) = [2 + (sqrt(k)+1)^2 / alpha] / log2(k) (§III-B d)."""
     if k <= 1.0:

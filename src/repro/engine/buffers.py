@@ -17,7 +17,8 @@ These two classes carry *all* of the operators' round accounting:
   ``ceil(V/c)`` read rounds.  With ``prefetch=True`` the cursor models the
   §IV-E double buffer: every refill after the first is issued one batch ahead
   and its RTT is hidden (accounted by the scheduler).  Sorted-run helpers
-  (``safe_bound`` / ``take_upto``) support merge consumers (EMS).
+  (``safe_bound`` / ``take_upto`` for keys, ``more`` / ``take`` for rows)
+  support merge consumers (EMS).
 """
 
 from __future__ import annotations
@@ -167,6 +168,21 @@ class PageCursor:
         if self.buffered == 0 or self.pos >= len(self.page_ids):
             return None
         return int(self._buf[-1])
+
+    @property
+    def buffer(self) -> Optional[np.ndarray]:
+        """The buffered elements, not consumed (:meth:`take` consumes them)."""
+        return self._buf
+
+    @property
+    def more(self) -> bool:
+        """Whether pages remain unread behind the buffer."""
+        return self.pos < len(self.page_ids)
+
+    def take(self, n: int) -> np.ndarray:
+        """Consume the first ``n`` buffered elements (rows, or keys)."""
+        out, self._buf = self._buf[:n], self._buf[n:]
+        return out
 
     def take_upto(self, bound: Optional[int]) -> np.ndarray:
         """Consume buffered elements ``<= bound`` (all of them when ``None``).

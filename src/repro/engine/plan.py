@@ -9,11 +9,15 @@ those queries:
 ``LogicalPlan``
   A tree of relational nodes — ``scan`` / ``filter`` / ``join`` /
   ``aggregate`` / ``sort`` — annotated with table statistics (sizes in
-  pages).  Filters scale the estimated pages flowing upward; a filter
-  chain feeding a BNLJ probe side additionally compiles *physically* — the
-  join task carries ``pushdown_sel`` (and the predicate, when given), so
-  the arbiter can ship the filtered scan to a compute-capable tier.  All
-  other filters remain stats annotations (pushdown-at-scan assumption);
+  pages).  Joins name their key column on each side and the columns each
+  keeps; aggregates their group columns and sums; sorts their key columns
+  and a limit.  Filters scale the estimated pages flowing upward.  A
+  filter with ``where`` terms over a scan compiles *physically* into the
+  operator that reads the scan (an EHJ side, EAGG's input), which runs it
+  on every block it reads; a filter chain feeding a BNLJ probe side
+  compiles too — the join task carries ``pushdown_sel`` (and the
+  predicate, when given), so the arbiter can ship the filtered scan to a
+  compute-capable tier.  All other filters remain stats annotations;
   ``CompiledPlan.pushed_filters`` / ``annotation_filters`` record which is
   which.
 
@@ -36,11 +40,12 @@ independent-selectivity estimate: each source join contributes a page
 selectivity ``phi = out / (|L| * |S|)`` applied once both its sides are
 joined.
 
-Skeleton assumption (documented, asserted nowhere): every join in a cluster
-equi-joins on the shared key column 0 — the convention of the synthetic
+Reordering assumes every join in a cluster equi-joins on the shared key
+column 0 of ``(key, payload)`` rows — the convention of the synthetic
 relations (``make_relation``) and of operator outputs (``_block_join``
-keeps the key in column 0) — which is what makes reordering semantically
-valid.
+keeps the key in column 0).  A cluster whose joins name other key columns
+or a projection (``on``/``keep``) keeps its written tree, each join lowered
+with its own key columns, projection and options.
 """
 
 from __future__ import annotations
@@ -52,6 +57,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.engine.registry import WorkloadStats, get
 from repro.engine.session import OperatorTask, Session, TaskOutput
+from repro.remote.rows import Term, check_sum, check_where
 
 # --------------------------------------------------------------------------
 # Logical nodes
@@ -72,6 +78,10 @@ class Node:
     selectivity: float = 1.0  # filter only
     out_pages: Optional[float] = None  # join/aggregate estimate override
     options: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    # What the node computes, lowered onto its operator: a join's ``on`` and
+    # ``keep``, a filter's ``where``, an aggregate's ``by`` and ``sums``, a
+    # sort's ``by`` and ``limit``; empty for the column-0 defaults.
+    params: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     @property
     def pages(self) -> float:
@@ -103,11 +113,12 @@ def _relation_pages(relation: Any) -> int:
 class LogicalPlan:
     """Builder for a relational tree; the last node built is the root.
 
-    >>> lp = LogicalPlan("q3")
-    >>> o = lp.scan("orders", orders_rel)
-    >>> li = lp.scan("lineitem", lineitem_rel)
-    >>> j = lp.join(lp.filter(o, 0.5), li, out_pages=30.0)
-    >>> lp.aggregate(j, out_pages=4.0)
+    >>> lp = LogicalPlan("q")
+    >>> o = lp.scan("orders", orders_rel)      # (orderkey, orderdate)
+    >>> li = lp.scan("lineitem", lineitem_rel)  # (orderkey, price)
+    >>> recent = lp.filter(o, 0.5, where=[(1, ">=", 1000)])
+    >>> j = lp.join(recent, li, on=(0, 0), keep=((1,), (1,)))
+    >>> lp.sort(lp.aggregate(j, by=[0, 1], sums=[2]), by=[(2, True)], limit=10)
     >>> tasks = compile_plan(session, lp).tasks
     """
 
@@ -139,7 +150,8 @@ class LogicalPlan:
 
     def filter(self, child: Node, selectivity: float,
                name: Optional[str] = None,
-               predicate: Optional[Callable[..., bool]] = None) -> Node:
+               predicate: Optional[Callable[..., bool]] = None,
+               where: Optional[Sequence[Term]] = None) -> Node:
         """Scale the child's estimated pages by ``selectivity`` (0, 1].
 
         ``selectivity`` must be finite — ``nan``/``inf`` raise here instead
@@ -148,6 +160,13 @@ class LogicalPlan:
         compiled physically (BNLJ probe side), the predicate executes at the
         data plane — at a compute-capable tier when the arbiter pushes it —
         while ``selectivity`` stays the planning estimate.
+
+        ``where`` is a conjunction of ``(column, comparison, constant)``
+        terms over the rows of a scan (:mod:`repro.remote.rows`).  Over a
+        scan (directly or through other filters) it compiles into the
+        operator that reads the scan — an EHJ build or probe side, or EAGG's
+        input — which runs it on every block it reads; anywhere else
+        ``compile_plan`` refuses it rather than drop it.
         """
         selectivity = float(selectivity)
         if not math.isfinite(selectivity) or not 0.0 < selectivity <= 1.0:
@@ -163,37 +182,78 @@ class LogicalPlan:
                     f"{type(predicate).__name__}"
                 )
             options["predicate"] = predicate
+        params: Dict[str, Any] = {}
+        if where is not None:
+            if predicate is not None:
+                raise ValueError("a filter takes a predicate or a where, not both")
+            params["where"] = check_where(where)
         return self._add(Node(
             kind="filter", name=self._name("filter", name),
             children=(self._node(child),), selectivity=selectivity,
-            options=options,
+            options=options, params=params,
         ))
 
     def join(self, left: Node, right: Node,
              out_pages: Optional[float] = None,
-             name: Optional[str] = None, **options: Any) -> Node:
-        """Equijoin on the shared key column; ``options`` reach the task."""
+             name: Optional[str] = None,
+             on: Optional[Tuple[int, int]] = None,
+             keep: Optional[Tuple[Sequence[int], Sequence[int]]] = None,
+             **options: Any) -> Node:
+        """Equijoin ``left[on[0]] == right[on[1]]``; ``options`` reach the task.
+
+        ``keep`` names the columns each side keeps; output rows are ``(key,
+        left kept columns..., right kept columns...)``.  Without ``on`` and
+        ``keep`` the join is on the shared key column 0 of ``(key, payload)``
+        rows, giving ``(key, left payload, right payload)``.
+        """
+        params: Dict[str, Any] = {}
+        if on is not None:
+            params["on"] = (int(on[0]), int(on[1]))
+        if keep is not None:
+            params["keep"] = (tuple(map(int, keep[0])), tuple(map(int, keep[1])))
         return self._add(Node(
             kind="join", name=self._name("join", name),
             children=(self._node(left), self._node(right)),
-            out_pages=out_pages, options=dict(options),
+            out_pages=out_pages, options=dict(options), params=params,
         ))
 
     def aggregate(self, child: Node, out_pages: Optional[float] = None,
-                  name: Optional[str] = None, **options: Any) -> Node:
-        """Group-by on the key column, lowered to EAGG."""
+                  name: Optional[str] = None,
+                  by: Optional[Sequence[int]] = None,
+                  sums: Optional[Sequence[Any]] = None,
+                  **options: Any) -> Node:
+        """Group-by, lowered to EAGG: on the columns ``by`` (default column
+        0), summing ``sums`` (columns or int64 expressions, see
+        :func:`repro.remote.eagg.eagg`; default column 1); output rows are
+        ``(group columns..., sums..., count)``."""
+        params: Dict[str, Any] = {}
+        if by is not None:
+            params["by"] = tuple(map(int, by))
+        if sums is not None:
+            params["sums"] = tuple(check_sum(s) for s in sums)
         return self._add(Node(
             kind="aggregate", name=self._name("agg", name),
             children=(self._node(child),), out_pages=out_pages,
-            options=dict(options),
+            options=dict(options), params=params,
         ))
 
     def sort(self, child: Node, name: Optional[str] = None,
-             **options: Any) -> Node:
-        """Order-by, lowered to EMS."""
+             by: Optional[Sequence[Tuple[int, bool]]] = None,
+             limit: Optional[int] = None, **options: Any) -> Node:
+        """Order-by, lowered to EMS: without ``by`` every value is a key;
+        with it, rows sort on ``(column, descending)`` pairs, ties broken by
+        the other columns ascending.  ``limit`` keeps the first rows."""
+        params: Dict[str, Any] = {}
+        if by is not None:
+            params["by"] = tuple((int(c), bool(d)) for c, d in by)
+        if limit is not None:
+            if int(limit) < 0:
+                raise ValueError(f"sort limit must be >= 0, got {limit}")
+            params["limit"] = int(limit)
         return self._add(Node(
             kind="sort", name=self._name("sort", name),
             children=(self._node(child),), options=dict(options),
+            params=params,
         ))
 
     @staticmethod
@@ -224,9 +284,9 @@ class JoinChoice:
     chosen_cost: float  # modeled L of the winning shape
     left_deep_cost: float  # modeled L of the hand-written tree
     candidates: Tuple[Tuple[str, float], ...]  # (description, modeled L)
-    # Filter nodes this cluster compiled physically onto a BNLJ probe side
-    # (the operator executes them — candidates for tier pushdown) rather
-    # than leaving them as pure stats annotations.
+    # Filter nodes this cluster compiled physically — ``where`` filters onto
+    # an EHJ side, or a chain onto a BNLJ probe side (candidates for tier
+    # pushdown) — rather than leaving them as pure stats annotations.
     pushed_filters: Tuple[str, ...] = ()
 
 
@@ -235,6 +295,10 @@ class _Cluster:
 
     def __init__(self, session: Session, join_op: str, policy: str):
         self.leaves: List[Node] = []
+        # Each join node by the leaf set it joins, and the tree as written
+        # (leaf indices, nested ``(left, right)`` pairs).
+        self.joins: Dict[frozenset, Node] = {}
+        self.written: Any = None
         self.est: Dict[frozenset, float] = {}
         self.preds: List[Tuple[frozenset, frozenset, float]] = []
         self.tau = session.tier.tau_pages
@@ -244,14 +308,18 @@ class _Cluster:
 
     def collect(self, node: Node) -> frozenset:
         """Flatten ``node``'s join subtree into leaves + predicates."""
+        s, self.written = self._collect(node)
+        return s
+
+    def _collect(self, node: Node) -> Tuple[frozenset, Any]:
         if node.kind != "join":
             idx = len(self.leaves)
             self.leaves.append(node)
             s = frozenset([idx])
             self.est[s] = max(node.pages, 1.0)
-            return s
-        ls = self.collect(node.children[0])
-        rs = self.collect(node.children[1])
+            return s, idx
+        ls, lt = self._collect(node.children[0])
+        rs, rt = self._collect(node.children[1])
         out = node.pages if node.out_pages is not None else max(
             self.est[ls], self.est[rs]
         )
@@ -259,7 +327,8 @@ class _Cluster:
         self.preds.append((ls, rs, phi))
         s = ls | rs
         self.est[s] = max(out, 1.0)
-        return s
+        self.joins[s] = node
+        return s, (lt, rt)
 
     def size_of(self, s: frozenset) -> float:
         """Estimated pages of the join over leaf set ``s``.
@@ -382,10 +451,11 @@ class CompiledPlan:
     root: OperatorTask
     plan: LogicalPlan
     join_choices: List[JoinChoice]
-    # Filter disposition across the whole plan: physically compiled onto a
-    # BNLJ probe side (arbiter decides ship vs. tier pushdown at plan time)
-    # vs. left as pure estimate annotations (ehj, build sides, non-leaf
-    # filters).  Names are logical-plan node names.
+    # Filter disposition across the whole plan: physically compiled — a
+    # ``where`` onto the operator reading its scan (EHJ side, EAGG input), a
+    # chain onto a BNLJ probe side (arbiter decides ship vs. tier pushdown
+    # at plan time) — vs. left as pure estimate annotations.  Names are
+    # logical-plan node names.
     pushed_filters: List[str] = dataclasses.field(default_factory=list)
     annotation_filters: List[str] = dataclasses.field(default_factory=list)
 
@@ -440,19 +510,61 @@ def compile_plan(
             return node.rows_per_page
         return leaf_rpp(node.children[0])
 
+    def where_chain(node: Node) -> Optional[Tuple[Tuple[Term, ...], List[str], Node]]:
+        """(terms, filter names, scan) of a filter chain over a scan that
+        carries ``where`` terms, else None."""
+        terms: List[Term] = []
+        names: List[str] = []
+        n = node
+        while n.kind == "filter":
+            if "where" in n.params:
+                terms.extend(n.params["where"])
+                names.append(n.name)
+            n = n.children[0]
+        if not names:
+            return None
+        if n.kind != "scan":
+            raise ValueError(
+                f"filter {names[0]!r}: a where executes only on a scan's rows; "
+                f"this one filters a {n.kind}")
+        return tuple(terms), names, n
+
+    def read(node: Node, op: str) -> Tuple[Any, float, Tuple[Term, ...], List[str]]:
+        """Lower an operator's input: (value, estimated pages, the where
+        terms the operator runs on the blocks it reads, their filters)."""
+        chain = where_chain(node)
+        if chain is None:
+            value, pages = lower(node)
+            return value, pages, (), []
+        terms, names, scan = chain
+        if op not in ("ehj", "eagg"):
+            raise ValueError(
+                f"filter {names[0]!r}: a where compiles onto an ehj side or an "
+                f"eagg input, not a {op} input")
+        return scan.relation, node.pages, terms, names
+
     def lower(node: Node) -> Tuple[Any, float]:
         """Returns (data-plane value or TaskOutput, estimated pages)."""
         if node.kind == "scan":
             return node.relation, node.pages
         if node.kind == "filter":
+            chain = where_chain(node)
+            if chain is not None:
+                raise ValueError(
+                    f"filter {chain[1][0]!r}: a where compiles onto an ehj side "
+                    f"or an eagg input only")
             value, _ = lower(node.children[0])
             return value, node.pages
         if node.kind == "join":
             return lower_join_cluster(node)
         if node.kind == "aggregate":
-            value, in_pages = lower(node.children[0])
+            value, in_pages, where, names = read(node.children[0], "eagg")
+            pushed_filters.extend(names)
             stats_kw, task_kw = stats_options(node)
             task_kw.setdefault("rows_per_page", leaf_rpp(node))
+            task_kw.update(node.params)
+            if where:
+                task_kw["where"] = where
             task = session.task(
                 "eagg",
                 WorkloadStats(size_r=in_pages, out=node.pages, **stats_kw),
@@ -461,9 +573,10 @@ def compile_plan(
             tasks.append(task)
             return task.output, node.pages
         if node.kind == "sort":
-            value, in_pages = lower(node.children[0])
+            value, in_pages, _, _ = read(node.children[0], "ems")
             stats_kw, task_kw = stats_options(node)
             task_kw.setdefault("rows_per_page", leaf_rpp(node))
+            task_kw.update(node.params)
             task = session.task(
                 "ems",
                 WorkloadStats(size_r=in_pages, out=node.pages, **stats_kw),
@@ -493,27 +606,50 @@ def compile_plan(
         return sel, (preds[0] if preds else None), max(n.pages, 1.0), names
 
     def lower_join_cluster(node: Node) -> Tuple[Any, float]:
-        """Flatten a maximal join subtree, pick a shape, emit join tasks."""
+        """Flatten a maximal join subtree, pick a shape, emit join tasks.
+
+        Reordering assumes every join of the cluster is on the shared key
+        column 0 and keeps ``(key, payload)`` rows; a cluster whose joins
+        name other key columns or a projection keeps its written tree.
+        """
         cluster = _Cluster(session, join_op, session.policy)
         cluster.collect(node)
+        keyed = any(j.params for j in cluster.joins.values())
+        if keyed and join_op != "ehj":
+            raise ValueError(
+                f"join {node.name!r}: joins on named columns lower to ehj, "
+                f"not {join_op}")
         choice: Optional[JoinChoice] = None
-        if optimize and len(cluster.leaves) > 2:
+        if optimize and not keyed and len(cluster.leaves) > 2:
             tree, choice = cluster.best(node.name)
+        elif keyed:
+            tree = cluster.written
         else:
             tree = cluster._left_deep(range(len(cluster.leaves)))
-        lowered = [lower(leaf) for leaf in cluster.leaves]
-        # Task options/rows_per_page follow the original top join node.
-        stats_kw, task_kw = stats_options(node)
-        rpp = leaf_rpp(node)
+        lowered = [read(leaf, join_op) if join_op == "ehj" else (*lower(leaf), (), [])
+                   for leaf in cluster.leaves]
         seq = [0]
-        cluster_pushed: List[str] = []
+        cluster_pushed = [name for leaf in lowered for name in leaf[3]]
 
-        def emit(t) -> Tuple[Any, frozenset]:
+        def join_node(s: frozenset) -> Node:
+            """The join a task lowers: its own node where the tree is the
+            written one, else the cluster's top.  A node that sets no
+            options takes the top's."""
+            own = cluster.joins.get(s) if tree == cluster.written else None
+            if own is None:
+                return node
+            if not own.options:
+                own = dataclasses.replace(own, options=node.options)
+            return own
+
+        def emit(t) -> Tuple[Any, frozenset, Tuple[Term, ...]]:
             if isinstance(t, int):
-                return lowered[t][0], frozenset([t])
-            lv, ls = emit(t[0])
-            rv, rs = emit(t[1])
+                return lowered[t][0], frozenset([t]), lowered[t][2]
+            lv, ls, lwhere = emit(t[0])
+            rv, rs, rwhere = emit(t[1])
             s = ls | rs
+            jn = join_node(s)
+            stats_kw, task_kw = stats_options(jn)
             stats = WorkloadStats(
                 size_r=cluster.size_of(ls), size_s=cluster.size_of(rs),
                 out=cluster.size_of(s), **stats_kw,
@@ -524,7 +660,12 @@ def compile_plan(
             kw = dict(task_kw)
             if join_op == "ehj":
                 inputs = {"build": lv, "probe": rv}
-                kw.setdefault("rows_per_page", rpp)
+                kw.setdefault("rows_per_page", leaf_rpp(jn))
+                kw.update(jn.params)
+                if lwhere:
+                    kw["build_where"] = lwhere
+                if rwhere:
+                    kw["probe_where"] = rwhere
             else:
                 inputs = {"outer": lv, "inner": rv}
                 # A filter chain feeding the probe (inner) side compiles
@@ -547,9 +688,9 @@ def compile_plan(
                 join_op, stats, inputs=inputs, label=label, **kw,
             )
             tasks.append(task)
-            return task.output, s
+            return task.output, s, ()
 
-        value, s = emit(tree)
+        value, s, _ = emit(tree)
         if choice is not None:
             choices.append(dataclasses.replace(
                 choice, pushed_filters=tuple(cluster_pushed),

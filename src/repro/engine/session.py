@@ -742,22 +742,60 @@ class Session:
         pins = [self._primary_pin(t) for t in tasks]
         return pins if any(p is not None for p in pins) else None
 
-    def plan(self, tasks: Sequence[OperatorTask], dag: bool = False):
+    @classmethod
+    def stages(cls, tasks: Sequence[OperatorTask]) -> List[List[int]]:
+        """The DAG's tasks (list indices) in stages that run one after another.
+
+        Stages follow the DAG scheduler's order (the lowest ready index
+        first), cut wherever every task run so far is an ancestor of every
+        task still to run: no task of a stage can start before the stages
+        ahead of it have finished.  A chain is one stage a task; independent
+        subtrees share a stage.
+        """
+        deps = cls._dag_deps(tasks)
+        ancestors: List[set] = [set() for _ in tasks]
+        order: List[int] = []
+        while len(order) < len(tasks):
+            i = next(j for j in range(len(tasks))
+                     if j not in order and deps[j] <= set(order))
+            for d in deps[i]:
+                ancestors[i] |= ancestors[d] | {d}
+            order.append(i)
+        out = [[order[0]]]
+        for k in range(1, len(order)):
+            if all(set(order[:k]) <= ancestors[j] for j in order[k:]):
+                out.append([])
+            out[-1].append(order[k])
+        return [sorted(stage) for stage in out]
+
+    def plan(self, tasks: Sequence[OperatorTask], dag: bool = False,
+             stages: bool = False):
         """Arbitrate the session budget (and placements) across ``tasks``.
 
         ``dag=True`` validates the tasks as a DAG (any topological wiring)
         instead of requiring list order to be execution order.
+        ``stages=True`` (a DAG) arbitrates the whole budget over each of
+        :meth:`stages` on its own: a task's output is pages, written before
+        a later stage starts, so tasks of different stages never hold their
+        buffers at the same time and each stage may use the whole budget.
         """
         from repro.engine.pipeline import _plan_pipeline
 
-        tasks = self._check_tasks(tasks, dag=dag)
+        tasks = self._check_tasks(tasks, dag=dag or stages)
         target = self.hierarchy if self.hierarchy is not None else self.tier
-        return _plan_pipeline(
-            [t.op for t in tasks], [t.stats for t in tasks],
-            target, self.budget, self.policy, self.step,
-            eviction=self.evictor is not None,
-            pinned=self._task_pins(tasks),
-        )
+        groups = self.stages(tasks) if stages else [list(range(len(tasks)))]
+        ops: List[Any] = [None] * len(tasks)
+        for group in groups:
+            part = [tasks[i] for i in group]
+            pplan = _plan_pipeline(
+                [t.op for t in part], [t.stats for t in part],
+                target, self.budget, self.policy, self.step,
+                eviction=self.evictor is not None,
+                pinned=self._task_pins(part),
+            )
+            for i, ob in zip(group, pplan.ops):
+                ops[i] = ob
+        return dataclasses.replace(pplan, ops=tuple(ops))
 
     @staticmethod
     def _check_plan_matches(pplan, tasks: Sequence[OperatorTask]) -> None:
@@ -945,6 +983,7 @@ class Session:
         plan=None,
         replan_threshold: Optional[float] = None,
         schedule: str = "serial",
+        stages: bool = False,
     ) -> SessionRunResult:
         """Execute ``tasks`` in order against the session's shared ledger.
 
@@ -971,6 +1010,9 @@ class Session:
         result carries ``makespan_seconds`` — the Eq.-(1) wall clock with
         independent subtrees overlapped under per-tier processor sharing.
         A linear chain reproduces the serial schedule's ledgers exactly.
+        ``stages=True`` (with ``schedule="dag"``) gives each of the DAG's
+        :meth:`stages` the whole budget (:meth:`plan`), and a replan
+        re-splits each stage's budget over its unfinished tasks.
         """
         if replan not in (None, "measured"):
             raise ValueError(
@@ -992,8 +1034,10 @@ class Session:
         if schedule == "dag":
             return self._run_dag(
                 tasks, replan=replan, plan=plan,
-                replan_threshold=replan_threshold,
+                replan_threshold=replan_threshold, stages=stages,
             )
+        if stages:
+            raise ValueError('stages=True needs schedule="dag"')
         tasks = self._check_tasks(tasks)
         pplan = plan if plan is not None else self.plan(tasks)
         self._check_plan_matches(pplan, tasks)
@@ -1050,6 +1094,7 @@ class Session:
         replan: Optional[str],
         plan,
         replan_threshold: Optional[float],
+        stages: bool = False,
     ) -> SessionRunResult:
         """DAG scheduler: dependency-ordered execution + overlapped makespan.
 
@@ -1063,10 +1108,12 @@ class Session:
         multi-tenant ``Server`` uses cross-query, re-used intra-query.
         """
         tasks = self._check_tasks(tasks, dag=True)
-        pplan = plan if plan is not None else self.plan(tasks, dag=True)
+        pplan = plan if plan is not None else self.plan(
+            tasks, dag=True, stages=stages)
         self._check_plan_matches(pplan, tasks)
         deps = self._dag_deps(tasks)
         n = len(tasks)
+        groups = self.stages(tasks) if stages else [list(range(n))]
         budgets = list(pplan.ops)
         cur_stats = [ob.stats for ob in budgets]
         replanned = [False] * n
@@ -1109,12 +1156,16 @@ class Session:
                             and self.estimate_error(ob.stats, measured)
                             <= replan_threshold):
                         continue
-                    budget_rem = self.budget - sum(
-                        budgets[k].m_pages for k in range(n) if done[k]
-                    )
+                    # Each stage's budget, less what its finished tasks
+                    # held, over its unfinished tasks.
+                    open_groups = [
+                        ([j for j in g if not done[j]],
+                         self.budget - sum(budgets[k].m_pages
+                                           for k in g if done[k]))
+                        for g in groups if not all(done[j] for j in g)
+                    ]
                     event = self._replan_indices(
-                        tasks, budgets, cur_stats, remaining, budget_rem,
-                        i, measured,
+                        tasks, budgets, cur_stats, open_groups, i, measured,
                     )
                     if event is not None:
                         events.append(event)
@@ -1201,7 +1252,8 @@ class Session:
         budget_rem = self.budget - sum(budgets[k].m_pages
                                        for k in range(done + 1))
         return self._replan_indices(
-            tasks, budgets, cur_stats, remaining, budget_rem, done, measured
+            tasks, budgets, cur_stats, [(remaining, budget_rem)], done,
+            measured,
         )
 
     def _replan_indices(
@@ -1209,19 +1261,20 @@ class Session:
         tasks: Sequence[OperatorTask],
         budgets: List[Any],
         cur_stats: List[WorkloadStats],
-        remaining: Sequence[int],
-        budget_rem: float,
+        groups: Sequence[Tuple[Sequence[int], float]],
         done: int,
         measured: WorkloadStats,
     ) -> Optional[ReplanEvent]:
-        """Re-arbitrate ``budget_rem`` over the ``remaining`` task indices.
+        """Re-arbitrate each group's budget over its task indices.
 
-        The index-list generalization shared by the serial tail replan and
-        the DAG scheduler's frontier replan (the frontier is not a list
-        suffix once independent subtrees interleave).
+        ``groups`` holds ``(task indices, remaining budget)`` pairs: one for
+        the serial tail replan and the DAG scheduler's frontier replan (the
+        frontier is not a list suffix once independent subtrees
+        interleave), one per unfinished stage with ``stages=True``.
         """
         from repro.engine.pipeline import _modeled_latency
 
+        remaining = [j for g, _ in groups for j in g]
         finished_task = tasks[done]
         before_m = tuple(budgets[j].m_pages for j in remaining)
         before_p = tuple(budgets[j].placement for j in remaining)
@@ -1237,11 +1290,13 @@ class Session:
             for j in remaining
         )
         try:
-            new_budgets = self._arbitrate_tail(
-                [tasks[j] for j in remaining],
-                [cur_stats[j] for j in remaining],
-                budget_rem,
-            )
+            new_budgets = [
+                nb for g, budget_rem in groups
+                for nb in self._arbitrate_tail(
+                    [tasks[j] for j in g], [cur_stats[j] for j in g],
+                    budget_rem,
+                )
+            ]
         except ValueError:
             # No feasible re-split (e.g. measured residency ate the capacity
             # the estimate assumed): keep the current plan rather than fail a

@@ -17,8 +17,9 @@ accounting) and every operator output is byte-identical, because
   * device mirrors only hold pages that round-trip losslessly (jax
     canonicalizes 64-bit dtypes to 32-bit with x64 off — flipping
     ``jax_enable_x64`` globally would contaminate every other suite in the
-    process, so int64 pages mirror as int32 only when every value fits;
-    everything else stays host-pinned and is counted),
+    process, so int64 pages mirror as int32 where every value fits, and as
+    their int32 bit view, two words a value, where one does not; any other
+    dtype stays host-pinned and is counted),
   * the kernel hooks fall back to the numpy reference whenever a block is
     not losslessly representable (counted in ``wall.kernel_fallbacks``), and
   * the hooks compute the same functions: sorted keys are sorted keys, and
@@ -66,12 +67,11 @@ def _group_by_part(rows, parts, max_key: int, interpret: bool):
         return gather_rows(rows, order, interpret=interpret)
 
 
-def _device_page(page: np.ndarray) -> Optional[np.ndarray]:
-    """A device-representable view of a host page, or ``None`` when lossy.
+def _as_int32(page: np.ndarray) -> Optional[np.ndarray]:
+    """A host block as int32 values, or ``None`` when that is lossy.
 
-    int32/float32 pages mirror as-is; int64 pages mirror as int32 only when
-    every value round-trips exactly.  Anything else host-pins — parity with
-    the simulator always beats device coverage.
+    int32/float32 blocks pass as-is; int64 blocks narrow to int32 only when
+    every value round-trips exactly.  This is what the kernels compute on.
     """
     page = np.asarray(page)
     if page.dtype == np.int64:
@@ -80,6 +80,24 @@ def _device_page(page: np.ndarray) -> Optional[np.ndarray]:
         return page.astype(np.int32)
     if page.dtype in (np.int32, np.float32):
         return page
+    return None
+
+
+def _device_page(page: np.ndarray) -> Optional[Tuple[np.ndarray, bool]]:
+    """A page's device mirror and whether it is wide, or ``None`` (host-pin).
+
+    A page :func:`_as_int32` takes mirrors as those values.  Any other int64
+    page is *wide*: it mirrors as its int32 bit view, two words a value
+    (``(rows, 2 * columns)``), which :meth:`BackendTier.read_batch` views
+    back as int64 bit for bit.  Other dtypes host-pin — parity with the
+    simulator always beats device coverage.
+    """
+    page = np.asarray(page)
+    narrow = _as_int32(page)
+    if narrow is not None:
+        return narrow, False
+    if page.dtype == np.int64:
+        return np.ascontiguousarray(page).view(np.int32), True
     return None
 
 
@@ -194,6 +212,7 @@ class BackendTier(RemoteMemory):
         self._wall = wall
         self._device = device
         self._dev: Dict[int, jax.Array] = {}
+        self._wide: set = set()  # pages mirrored as their int32 bit view
         self._in_write = False
 
     # -- mirroring -----------------------------------------------------------
@@ -202,11 +221,13 @@ class BackendTier(RemoteMemory):
         views = []
         with span("tier.check"):
             for i in page_ids:
-                v = _device_page(self._store[i])
-                if v is None:
+                mirror = _device_page(self._store[i])
+                if mirror is None:
                     self._wall.host_pinned_pages += 1
-                else:
-                    views.append((i, v))
+                    continue
+                views.append((i, mirror[0]))
+                if mirror[1]:
+                    self._wide.add(i)
         if not views:
             return
         nbytes = sum(v.nbytes for _, v in views)
@@ -255,10 +276,14 @@ class BackendTier(RemoteMemory):
                 )
                 for (k, _), p in zip(live, pulled):
                     fetched[k] = p
+            # A wide mirror's word pairs view back as int64 from a C-ordered
+            # copy: a pulled array need not be C-contiguous on every device.
             with span("tier.cast"):
                 return [
-                    h if f is None else f.astype(h.dtype, copy=False)
-                    for h, f in zip(host, fetched)
+                    h if f is None
+                    else np.array(f, order="C").view(np.int64) if i in self._wide
+                    else f.astype(h.dtype, copy=False)
+                    for i, h, f in zip(page_ids, host, fetched)
                 ]
 
     def free(self, page_ids: Iterable[int]) -> None:
@@ -266,6 +291,7 @@ class BackendTier(RemoteMemory):
         super().free(ids)
         for i in ids:
             self._dev.pop(i, None)
+            self._wide.discard(i)
 
 
 class ExecutionBackend(MemoryHierarchy):
@@ -318,6 +344,9 @@ class ExecutionBackend(MemoryHierarchy):
             dev = self.tiers[src]._dev.pop(i, None)
             if dev is not None:
                 self.tiers[cur]._dev[i] = dev
+            if i in self.tiers[src]._wide:
+                self.tiers[src]._wide.discard(i)
+                self.tiers[cur]._wide.add(i)
 
     # -- operator compute hooks ------------------------------------------------
 
@@ -336,7 +365,7 @@ class ExecutionBackend(MemoryHierarchy):
                 keys = np.asarray(keys)
                 if keys.ndim != 1 or keys.size < 2:
                     return np.sort(keys, kind="stable")
-                dev = _device_page(keys) if keys.dtype.kind in "iu" else None
+                dev = _as_int32(keys) if keys.dtype.kind in "iu" else None
                 if dev is None:
                     self.wall.kernel_fallbacks += 1
                     return np.sort(keys, kind="stable")
@@ -383,7 +412,7 @@ class ExecutionBackend(MemoryHierarchy):
                 # The largest id the int32 composite key admits at this
                 # length: a function of n_pad alone, so it adds no program.
                 key_bound = (2**31 - 1) // n_pad - 1
-                dev_rows = _device_page(rows) if rows.ndim == 2 else None
+                dev_rows = _as_int32(rows) if rows.ndim == 2 else None
                 eligible = (
                     dev_rows is not None
                     and parts.dtype.kind in "iu"

@@ -9,7 +9,9 @@ through R_o); P3 re-reads each spilled pair, indexes its build partition once
 and probes it block by block.  A partition's table is a :class:`KeyIndex`:
 its keys are sorted once, and each probe row is searched in them once
 (``probe_index``), so a partition probed in many blocks is never searched
-again per block.  The R_w/R_s/R_o pools are
+again per block.  Rows may be of any width: each side names its key column
+and the columns it keeps, and may carry a filter that runs on each block as
+it is read.  The R_w/R_s/R_o pools are
 per-partition-sliced :class:`repro.engine.BufferPool` instances and every
 block read is a :class:`repro.engine.PageCursor` round, so the ledger counts
 match the Table V terms.
@@ -18,7 +20,7 @@ match the Table V terms.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, NamedTuple
+from typing import Dict, List, NamedTuple, Sequence, Tuple
 
 import numpy as np
 
@@ -27,6 +29,7 @@ from repro.spans import count, span
 from repro.engine.buffers import BufferPool, PageCursor
 from repro.engine.scheduler import TransferScheduler, stream_tiers
 from repro.remote.bnlj import _block_join
+from repro.remote.rows import Term, check_where, page_rows, partition_of, select
 from repro.remote.simulator import Relation, RemoteMemory, as_relation, relation_rows
 
 
@@ -55,11 +58,12 @@ class HashJoinResult:
 
 
 class KeyIndex(NamedTuple):
-    """One build partition's table: its keys sorted once, stably, with the
-    payloads in the same order, and whether no key repeats."""
+    """One build partition's table: its keys sorted once, stably, each other
+    column of its rows in the same order (one array a column), and whether
+    no key repeats."""
 
     keys: np.ndarray
-    payloads: np.ndarray
+    payloads: Tuple[np.ndarray, ...]
     unique: bool
 
 
@@ -72,21 +76,26 @@ def build_index(rows: np.ndarray) -> KeyIndex:
     """
     order = np.argsort(rows[:, 0], kind="stable")
     keys = rows[order, 0]
-    return KeyIndex(keys, rows[order, 1], not (keys[1:] == keys[:-1]).any())
+    payloads = tuple(rows[order, c] for c in range(1, rows.shape[1]))
+    return KeyIndex(keys, payloads, not (keys[1:] == keys[:-1]).any())
 
 
 def probe_index(index: KeyIndex, probe_rows: np.ndarray) -> np.ndarray:
     """Equijoin ``probe_rows`` against a build partition's index on column 0.
 
-    Returns ``(key, build payload, probe payload)`` rows in probe order: the
-    same pairs as ``_block_join(build_rows, probe_rows)``, which emits them
-    in build order.  Each probe key is searched once: one ``searchsorted``
-    where the build keys are unique, a left and a right one (and a repeat
-    by the match counts) where they are not.
+    Returns ``(key, build payload..., probe payload...)`` rows in probe
+    order, the payloads being every column but the key of each side: the
+    same pairs as ``_block_join(build_rows, probe_rows)`` on ``(key,
+    payload)`` rows, which emits them in build order.  Each probe key is
+    searched once: one ``searchsorted`` where the build keys are unique, a
+    left and a right one (and a repeat by the match counts) where they are
+    not.  Each payload column is gathered on its own.
     """
     keys, pk = index.keys, probe_rows[:, 0]
+    nb = len(index.payloads)
+    width = nb + probe_rows.shape[1]
     if not len(keys) or not len(pk):
-        return np.empty((0, 3), dtype=np.int64)
+        return np.empty((0, width), dtype=np.int64)
     # Searching the probe keys in ascending order lets each search start from
     # the last one's result; the positions are scattered back to probe order.
     order = np.argsort(pk)
@@ -100,22 +109,21 @@ def probe_index(index: KeyIndex, probe_rows: np.ndarray) -> np.ndarray:
     if index.unique:
         pos = search("left")
         np.minimum(pos, len(keys) - 1, out=pos)
-        hits = np.flatnonzero(keys[pos] == pk)
-        out = np.empty((len(hits), 3), dtype=np.int64)
-        out[:, 0] = pk[hits]
-        out[:, 1] = index.payloads[pos[hits]]
-        out[:, 2] = probe_rows[hits, 1]
-        return out
-    lo = search("left")
-    counts = search("right") - lo
-    total = int(counts.sum())
-    probe_idx = np.repeat(np.arange(len(pk)), counts)
-    # Build position of each pair: its key run's start plus its rank in it.
-    build_idx = np.repeat(lo - (np.cumsum(counts) - counts), counts) + np.arange(total)
-    out = np.empty((total, 3), dtype=np.int64)
+        probe_idx = np.flatnonzero(keys[pos] == pk)
+        build_idx = pos[probe_idx]
+    else:
+        lo = search("left")
+        counts = search("right") - lo
+        total = int(counts.sum())
+        probe_idx = np.repeat(np.arange(len(pk)), counts)
+        # Build position of each pair: its key run's start plus its rank in it.
+        build_idx = np.repeat(lo - (np.cumsum(counts) - counts), counts) + np.arange(total)
+    out = np.empty((len(probe_idx), width), dtype=np.int64)
     out[:, 0] = pk[probe_idx]
-    out[:, 1] = index.payloads[build_idx]
-    out[:, 2] = probe_rows[probe_idx, 1]
+    for c, column in enumerate(index.payloads, start=1):
+        out[:, c] = column[build_idx]
+    for c in range(1, probe_rows.shape[1]):
+        out[:, nb + c] = probe_rows[probe_idx, c]
     return out
 
 
@@ -142,6 +150,11 @@ def ehj(
     rows_per_page: int | None = None,
     prefetch: bool = False,
     tier=None,
+    on: Tuple[int, int] = (0, 0),
+    keep: Tuple[Sequence[int], Sequence[int]] = ((1,), (1,)),
+    build_where: Sequence[Term] = (),
+    probe_where: Sequence[Term] = (),
+    page_bytes: int | None = None,
 ) -> HashJoinResult:
     """Run the three-phase external hash join under `plan`.
 
@@ -150,11 +163,29 @@ def ehj(
     are routed to — a scalar, or a per-stream spec over ``STREAMS`` (e.g.
     spilled build partitions on DRAM, staged probe tuples on SSD).
     ``build`` / ``probe`` accept a ``Relation`` or a bare page-id list.
+
+    The join is ``build[on[0]] == probe[on[1]]``.  Each block is filtered by
+    its side's ``*_where`` (a conjunction of ``(column, comparison,
+    constant)`` terms, :mod:`repro.remote.rows`) as soon as it is read, then
+    cut to ``(key, kept columns...)`` with ``keep`` naming each side's kept
+    columns, before it is hashed; a filter adds no round.  Output rows are
+    ``(key, build kept columns..., probe kept columns...)``; the defaults
+    join ``(key, payload)`` rows on column 0 into ``(key, build payload,
+    probe payload)``.  Every stream's pages hold ``rows_per_page`` rows, or
+    with ``page_bytes`` as many rows of the stream's width as fill that
+    many bytes of int64.
     """
     build = as_relation(remote, build)
     probe = as_relation(remote, probe)
     tiers = stream_tiers(tier, STREAMS)
     rows_per_page = rows_per_page or build.rows_per_page
+    build_cols = (int(on[0]), *map(int, keep[0]))
+    probe_cols = (int(on[1]), *map(int, keep[1]))
+    build_where, probe_where = check_where(build_where), check_where(probe_where)
+    out_width = len(build_cols) + len(probe_cols) - 1
+    build_rpp, probe_rpp, out_rpp = (page_rows(w, rows_per_page, page_bytes)
+                                     for w in (len(build_cols), len(probe_cols), out_width))
+
     p = plan.partitions
     n_spilled = int(round(plan.sigma * p))
     spilled = set(range(p - n_spilled, p))  # deterministic spill set
@@ -162,15 +193,24 @@ def ehj(
     before = sched.snapshot()
     phase_rounds: Dict[str, int] = {}
 
+    def side(rows: np.ndarray, where, cols) -> np.ndarray:
+        """A block read from one side, filtered and cut to (key, kept...)."""
+        if not where:
+            return select(rows, where, cols)
+        with span("ehj.filter"):
+            out = select(rows, where, cols)
+        count("filter.rows_in", len(rows))
+        count("filter.rows_out", len(out))
+        return out
+
     def hash_part(keys: np.ndarray) -> np.ndarray:
         with span("ehj.hash"):
-            h = keys.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
-            return ((h >> np.uint64(33)) % np.uint64(p)).astype(np.int64)
+            return partition_of(keys, p)
 
     def table(blocks: List[np.ndarray]) -> KeyIndex:
         with span("ehj.table"):
             rows = (np.concatenate(blocks, axis=0) if blocks
-                    else np.empty((0, 2), dtype=np.int64))
+                    else np.empty((0, len(build_cols)), dtype=np.int64))
             return build_index(rows)
 
     def join(index: KeyIndex, probe_rows: np.ndarray) -> np.ndarray:
@@ -184,13 +224,14 @@ def ehj(
     with span("ehj.P1"):
         t0 = sched.snapshot()
         r_r1, r_w1 = plan.p1
-        build_pool = BufferPool(sched, r_w1, rows_per_page,
+        build_pool = BufferPool(sched, r_w1, build_rpp,
                                 n_streams=max(len(spilled), 1),
                                 tier=tiers["build"])
         resident_build: Dict[int, List[np.ndarray]] = {
             q: [] for q in range(p) if q not in spilled}
         for rows in PageCursor(sched, build.page_ids, round(r_r1),
                                prefetch=prefetch).blocks():
+            rows = side(rows, build_where, build_cols)
             parts = hash_part(rows[:, 0])
             for q, sel in sched.partitions(rows, parts):
                 if q in spilled:
@@ -205,13 +246,14 @@ def ehj(
     with span("ehj.P2"):
         t0 = sched.snapshot()
         r_r2, r_s2, r_o2 = plan.p2
-        stage_pool = BufferPool(sched, r_s2, rows_per_page,
+        stage_pool = BufferPool(sched, r_s2, probe_rpp,
                                 n_streams=max(len(spilled), 1),
                                 tier=tiers["stage"])
-        out_pool = BufferPool(sched, r_o2, rows_per_page, tier=tiers["output"])
+        out_pool = BufferPool(sched, r_o2, out_rpp, tier=tiers["output"])
         output_rows = 0
         for rows in PageCursor(sched, probe.page_ids, round(r_r2),
                                prefetch=prefetch).blocks():
+            rows = side(rows, probe_where, probe_cols)
             parts = hash_part(rows[:, 0])
             for q, sel in sched.partitions(rows, parts):
                 if q in spilled:
@@ -229,7 +271,7 @@ def ehj(
         t0 = sched.snapshot()
         r_r3, r_o3 = plan.p3
         read_pages = round(r_r3)
-        ext_out_pool = BufferPool(sched, r_o3, rows_per_page, tier=tiers["output"])
+        ext_out_pool = BufferPool(sched, r_o3, out_rpp, tier=tiers["output"])
         for q in sorted(spilled):
             b_ids = build_pool.pages(q)
             q_ids = stage_pool.pages(q)

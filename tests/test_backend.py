@@ -131,16 +131,17 @@ def test_out_of_int32_range_keys_fall_back_but_agree():
 
 
 def test_host_pinned_pages_round_trip_unchanged():
-    """Pages whose values exceed int32 never get a device mirror, yet reads
-    return them bit-exact (the host copy is authoritative)."""
+    """Pages of a dtype the device cannot hold losslessly (float64) never get
+    a device mirror, yet reads return them bit-exact (the host copy is
+    authoritative).  int64 pages beyond int32 are mirrored wide instead."""
     backend = make_backend(*ONE)
-    big = np.array([2**40, 2**41, 3], dtype=np.int64)
+    big = np.array([2.0**60 + 0.5, 2.0**-40, 3.0], dtype=np.float64)
     small = np.arange(5, dtype=np.int64)
     ids = backend.put_local([big, small])
     assert backend.wall.host_pinned_pages == 1
     got = backend.read_batch(ids)
     np.testing.assert_array_equal(got[0], big)
-    assert got[0].dtype == np.int64
+    assert got[0].dtype == np.float64
     np.testing.assert_array_equal(got[1], small)
     assert got[1].dtype == np.int64
 

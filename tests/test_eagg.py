@@ -21,7 +21,7 @@ from repro.core.policies import (
 )
 from repro.engine import WorkloadStats, plan_operator
 from repro.remote import RemoteMemory, Relation, eagg, eagg_oracle
-from repro.remote.eagg import _hash_part
+from repro.remote.rows import partition_of
 
 TIER = TESTBED["remon_tcp"]
 ROWS = 8
@@ -48,7 +48,7 @@ def _mk_relation(remote, n_pages, domain, seed=0, skew=0.0):
 def _exact_inputs(remote, rel, plan):
     """Recompute the skew-aware workload detail eagg_costs_exact needs."""
     rows = np.concatenate(remote.peek_batch(rel.page_ids), axis=0)
-    parts = _hash_part(rows[:, 0], plan.partitions)
+    parts = partition_of(rows[:, 0], plan.partitions)
     n_spilled = int(round(plan.sigma * plan.partitions))
     spilled = list(range(plan.partitions - n_spilled, plan.partitions))
     spilled_rows = [int((parts == q).sum()) for q in spilled]
