@@ -205,6 +205,15 @@ class BackendTier(RemoteMemory):
     host->device round, ``read_batch`` a timed device->host round, and the
     pages a read returns are the *device round-trips* (cast back to the host
     dtype), so the data operators consume really crossed the boundary.
+
+    Writes are write-behind: a write round issues its copy and returns
+    without waiting for it to land, so its seconds are the issue call.  No
+    one needs a written page until a read round fetches it, and the pull
+    (``np.asarray``) waits for exactly the buffers it touches, so any wait
+    left lands in the read round's seconds.  A copy in flight reads the
+    page's host array, which is never mutated once written (the store keeps
+    the caller's array).  Seeding (``put_local`` outside a write round)
+    still waits, so placed inputs have landed when it returns.
     """
 
     def __init__(self, tier: TierSpec, wall: WallClock, device, _alloc=None):
@@ -234,10 +243,12 @@ class BackendTier(RemoteMemory):
         t0 = time.perf_counter()
         with span("tier.put"):
             arrays = jax.device_put([v for _, v in views], self._device)
-            jax.block_until_ready(arrays)
+            if not self._in_write:  # seeding waits: placed inputs have landed
+                jax.block_until_ready(arrays)
         elapsed = time.perf_counter() - t0
         if self._in_write:  # seeding (put_local) is not a transfer round
             self._wall.record_h2d(self.tier.name, elapsed, nbytes)
+            count("tier.writes_behind", sum(not a.is_ready() for a in arrays))
         for (i, _), arr in zip(views, arrays):
             self._dev[i] = arr
 
@@ -267,6 +278,7 @@ class BackendTier(RemoteMemory):
             fetched: List[Optional[np.ndarray]] = [None] * len(page_ids)
             live = [(k, d) for k, d in enumerate(mirrors) if d is not None]
             if live:
+                count("tier.reads_waited", sum(not d.is_ready() for _, d in live))
                 t0 = time.perf_counter()
                 with span("tier.pull"):
                     pulled = [np.asarray(d) for _, d in live]

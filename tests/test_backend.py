@@ -15,13 +15,16 @@ CPU, where every distinct block length is a fresh interpreted program.
 
 import dataclasses
 
+import jax
 import numpy as np
+import pytest
 
+from repro import spans
 from repro.core import TABLE_I
 from repro.engine import Session, WorkloadStats
 from repro.engine.registry import hierarchy_spec
 from repro.remote import MemoryHierarchy, make_backend
-from repro.remote.backend import ExecutionBackend
+from repro.remote.backend import BackendTier, ExecutionBackend, _device_page
 from repro.remote.simulator import make_key_pages, make_relation
 
 ROWS = 4
@@ -176,3 +179,134 @@ def test_migrate_keeps_device_mirrors_consistent():
     for page, back in zip(pages, got):
         np.testing.assert_array_equal(page, back)
         assert back.dtype == np.int64
+
+
+# -- write-behind: a write round returns before its copy lands ---------------
+
+# One page of each mirror kind: int32 as-is, int64 narrowed to int32, int64
+# mirrored wide as word pairs, and float64 host-pinned.
+KINDS = {
+    "narrow_int32": lambda k: np.arange(8, dtype=np.int32).reshape(ROWS, 2) + k,
+    "narrow_int64": lambda k: np.arange(8, dtype=np.int64).reshape(ROWS, 2) - k,
+    "wide_int64": lambda k: (np.arange(8, dtype=np.int64).reshape(ROWS, 2)
+                             * 2**40 - 2**62 + k),
+    "host_pinned": lambda k: np.linspace(-1.5, 2.0**60, 8).reshape(ROWS, 2) + k,
+}
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_read_right_after_write_round_is_bit_exact(kind):
+    backend = make_backend(*ONE)
+    pages = [KINDS[kind](k) for k in range(3)]
+    ids = backend.write_batch(pages)
+    got = backend.read_batch(ids)
+    for page, back in zip(pages, got):
+        assert back.dtype == page.dtype
+        assert back.shape == page.shape
+        assert back.tobytes() == page.tobytes()
+    pinned = kind == "host_pinned"
+    assert backend.wall.host_pinned_pages == (3 if pinned else 0)
+    wall = backend.wall.tiers["tcp"]
+    assert wall.h2d_rounds == (0 if pinned else 1)
+    assert wall.d2h_rounds == (0 if pinned else 1)
+
+
+def _every_kind():
+    return [make(k) for k, make in enumerate(KINDS.values())]
+
+
+@pytest.mark.parametrize("then", ["free", "migrate"])
+def test_page_in_flight_frees_and_migrates_like_the_simulator(then):
+    sim = MemoryHierarchy(hierarchy_spec(*THREE))
+    backend = make_backend(*THREE)
+    pages = _every_kind()
+    for remote in (sim, backend):
+        ids = remote.write_batch(pages)  # top tier, copies possibly in flight
+        if then == "free":
+            remote.free(ids)
+            assert remote.resident_ids() == []
+        else:
+            remote.migrate(ids, "ssd")
+            for page, back in zip(pages, remote.read_batch(ids)):
+                assert back.dtype == page.dtype
+                assert back.tobytes() == page.tobytes()
+    assert sim.snapshot() == backend.snapshot()
+    mirrored = [t._dev for t in backend.tiers]
+    if then == "free":
+        assert mirrored == [{}, {}, {}]
+    else:  # the three mirrored pages moved with their pages to ssd
+        assert [len(m) for m in mirrored] == [0, 0, 3]
+
+
+class _InFlight:
+    """A device array whose copy has not landed: the pull still works."""
+
+    def __init__(self, arr):
+        self.arr = arr
+
+    def is_ready(self):
+        return False
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.arr)
+
+
+@pytest.mark.parametrize("landing", ["as_it_comes", "in_flight"])
+def test_write_behind_counters_are_recorded(landing, monkeypatch):
+    if landing == "in_flight":  # every copy still in flight until pulled
+        device_put = jax.device_put
+        monkeypatch.setattr(jax, "device_put", lambda xs, device: [
+            _InFlight(a) for a in device_put(xs, device)])
+    backend = make_backend(*ONE)
+    pages = _every_kind()
+    spans.enable()
+    try:
+        with spans.Recorder(0) as rec:
+            ids = backend.write_batch(pages)
+            got = backend.read_batch(ids)
+    finally:
+        spans.disable()
+    for page, back in zip(pages, got):
+        assert back.tobytes() == page.tobytes()
+    mirrored = len(pages) - 1  # the float64 page is host-pinned
+    if landing == "in_flight":
+        assert rec.counts["tier.writes_behind"] == mirrored
+        assert rec.counts["tier.reads_waited"] == mirrored
+    else:
+        assert 0 <= rec.counts["tier.writes_behind"] <= mirrored
+        assert 0 <= rec.counts["tier.reads_waited"] <= mirrored
+    for name in ("tier.write", "tier.put", "tier.read", "tier.pull"):
+        assert rec.totals[name].calls == 1
+
+
+def test_store_and_wide_mirror_keep_the_callers_page():
+    """A write round's copy may still read the page's host array after the
+    round returns: the store holds the caller's array itself, and an int32
+    page's mirror is that array, a wide page's mirror a view of it."""
+    backend = make_backend(*ONE)
+    narrow = KINDS["narrow_int32"](0)
+    wide = KINDS["wide_int64"](0)
+    ids = backend.write_batch([narrow, wide])
+    stored = backend.peek_batch(ids)
+    assert stored[0] is narrow and stored[1] is wide
+    assert _device_page(narrow)[0] is narrow
+    mirror, is_wide = _device_page(wide)
+    assert is_wide and np.shares_memory(mirror, wide)
+
+
+def test_written_pages_stay_untouched_through_a_session(monkeypatch):
+    """No operator touches a page's host array once it is written, so a copy
+    still in flight reads the bytes the round wrote."""
+    written = []
+    write_batch = BackendTier.write_batch
+
+    def spy(self, pages):
+        written.extend((p, np.array(p, copy=True)) for p in pages)
+        return write_batch(self, pages)
+
+    monkeypatch.setattr(BackendTier, "write_batch", spy)
+    _run(make_backend(*THREE))
+    assert written
+    for page, before in written:
+        assert page.dtype == before.dtype
+        assert page.tobytes() == before.tobytes()

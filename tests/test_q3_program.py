@@ -392,6 +392,19 @@ def test_ems_limit_ledger_equals_its_closed_form(limit_rows):
 # --------------------------------------------------------------------------
 
 
+class _LandedMirror:
+    """A device mirror whose copy has landed and whose pull is ``pulled``."""
+
+    def __init__(self, pulled):
+        self.pulled = pulled
+
+    def is_ready(self):
+        return True
+
+    def __array__(self, dtype=None, copy=None):
+        return self.pulled
+
+
 def test_wide_int64_pages_mirror_losslessly():
     from repro.remote import make_backend
 
@@ -409,7 +422,7 @@ def test_wide_int64_pages_mirror_losslessly():
     tier = backend.tiers[0]
     assert tier._dev[ids[0]].shape == (3, 4) and tier._dev[ids[2]].shape == (3, 2)
     # A device may hand back a mirror that is not C-contiguous (a TPU does).
-    tier._dev[ids[0]] = np.asfortranarray(np.asarray(tier._dev[ids[0]]))
+    tier._dev[ids[0]] = _LandedMirror(np.asfortranarray(np.asarray(tier._dev[ids[0]])))
     assert np.array_equal(backend.read_batch(ids[:1])[0], wide)
     backend.free(ids)
     assert not tier._wide & set(ids)
